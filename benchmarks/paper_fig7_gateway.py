@@ -98,14 +98,16 @@ def _latency_part(
             window_p50 = []
             pooled = []
             for _ in range(windows):
-                start = len(rt.log)
+                # the log keeps only the most recent records: start the
+                # window's afresh
+                rt.log.clear()
                 for _ in range(per_session):
                     one_pass()
                 # keep warm hits (mode=warm) / cache reloads (mode=cold);
                 # any stray cold *init* is excluded
                 lat = [
                     r.wall_seconds
-                    for r in rt.log[start:]
+                    for r in list(rt.log)
                     if not r.cold and (r.warm == (mode == "warm"))
                 ]
                 window_p50.append(_percentile(lat, 0.50))

@@ -78,6 +78,7 @@ from dataclasses import dataclass, field
 from queue import Queue
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.core.scheduler import Scheduler
 from repro.core.stateful import FunctionRuntime, Session
 from repro.storage.tiers import TierStats, tier_accounting
@@ -182,6 +183,8 @@ class _Invocation:
     inputs: dict
     future: Future
     enqueued: float
+    #: the request id its spans carry (``obs``)
+    rid: int
 
 
 class _Lane:
@@ -332,7 +335,7 @@ class Gateway:
         scoped = self.scoped_session(app, session)
         item = _Invocation(
             fn_name, scoped, init_kwargs, inputs, Future(),
-            time.perf_counter(),
+            time.perf_counter(), obs.new_request(),
         )
         adm = self._admission
         with adm.cond:
@@ -525,10 +528,15 @@ class Gateway:
                         nxt = lane.pending.popleft()
                         stripe.waits.append(t0 - nxt.enqueued)
                         items.append(nxt)
+            for it in items:
+                obs.record("gateway.queue", it.enqueued, t0, it.rid,
+                           fn=it.fn_name, batch=len(items))
             aborting = self._abort
-            #: futures whose durable ack rides the shared batch ticket
-            deferred: List[Tuple[Future, Any]] = []
+            #: futures (with their request ids) whose durable ack rides
+            #: the shared batch ticket
+            deferred: List[Tuple[Future, Any, int]] = []
             ticket: Optional[Any] = None
+            executed = t0
             try:
                 if aborting:
                     # close(drain=False): fail fast instead of executing
@@ -547,11 +555,13 @@ class Gateway:
                             stats.errors += 1
                             item.future.set_exception(exc)
                         else:
+                            executed = time.perf_counter()
                             if tk is None:
                                 item.future.set_result(result)
                             else:
                                 ticket = tk
-                                deferred.append((item.future, result))
+                                deferred.append(
+                                    (item.future, result, item.rid))
                 else:
                     runnable = [
                         it for it in items
@@ -559,7 +569,13 @@ class Gateway:
                     ]
                     if runnable:
                         try:
-                            with tier_accounting(stats.tier):
+                            # one span, the lead's; the others' dispatch
+                            # is the same interval, recorded after it
+                            dispatched = time.perf_counter()
+                            with obs.span(
+                                "gateway.dispatch", rid=runnable[0].rid,
+                                fn=item.fn_name, batch=len(runnable),
+                            ) as attrs, tier_accounting(stats.tier):
                                 results = (
                                     self.runtime.invoke_batch_with_records(
                                         item.fn_name,
@@ -567,6 +583,7 @@ class Gateway:
                                         [(it.init_kwargs, it.inputs)
                                          for it in runnable],
                                         invoker=stats.invoker,
+                                        rids=[it.rid for it in runnable],
                                     )
                                 )
                         except BaseException as exc:
@@ -574,6 +591,10 @@ class Gateway:
                             for it in runnable:
                                 it.future.set_exception(exc)
                         else:
+                            executed = time.perf_counter()
+                            for it in runnable[1:]:
+                                obs.record("gateway.dispatch", dispatched,
+                                           executed, it.rid, **attrs)
                             for it, (outputs, record, error) in zip(
                                 runnable, results
                             ):
@@ -591,7 +612,8 @@ class Gateway:
                                 else:
                                     # one shared batch-final ticket
                                     ticket = record.commit_ticket
-                                    deferred.append((it.future, outputs))
+                                    deferred.append(
+                                        (it.future, outputs, it.rid))
                             self._touch_warm(
                                 item.fn_name, item.scoped_session
                             )
@@ -616,9 +638,13 @@ class Gateway:
                     # lands — the lane is already released, so the
                     # session keeps executing while its commit batches.
                     def _ack(t: Any,
-                             deferred: List[Tuple[Future, Any]] = deferred,
-                             stripe: _Stripe = stripe) -> None:
-                        for fut, result in deferred:
+                             deferred: List[Tuple[Future, Any, int]] = deferred,
+                             stripe: _Stripe = stripe,
+                             executed: float = executed) -> None:
+                        acked = time.perf_counter()
+                        for fut, result, rid in deferred:
+                            obs.record("gateway.ack", executed, acked, rid,
+                                       round=t.round)
                             if t.error is not None:
                                 fut.set_exception(t.error)
                             else:
@@ -637,21 +663,24 @@ class Gateway:
                 adm.cond.notify_all()
 
     def _execute(self, item: _Invocation, stats: InvokerStats) -> Any:
-        with tier_accounting(stats.tier):
-            outputs, record = self.runtime.invoke_with_record(
-                item.fn_name,
-                session=item.scoped_session,
-                init_kwargs=item.init_kwargs,
-                invoker=stats.invoker,
-                defer_commit=self.runtime.group_commit,
-                **item.inputs,
-            )
-        stats.invocations += 1
-        if record.warm:
-            stats.warm_hits += 1
-        else:
-            stats.cold_starts += 1
-        self._touch_warm(item.fn_name, item.scoped_session)
+        with obs.span("gateway.dispatch", rid=item.rid,
+                      fn=item.fn_name) as attrs:
+            with tier_accounting(stats.tier):
+                outputs, record = self.runtime.invoke_with_record(
+                    item.fn_name,
+                    session=item.scoped_session,
+                    init_kwargs=item.init_kwargs,
+                    invoker=stats.invoker,
+                    defer_commit=self.runtime.group_commit,
+                    **item.inputs,
+                )
+            attrs["warm"] = record.warm
+            stats.invocations += 1
+            if record.warm:
+                stats.warm_hits += 1
+            else:
+                stats.cold_starts += 1
+            self._touch_warm(item.fn_name, item.scoped_session)
         return outputs, record.commit_ticket
 
     def _retire(self, stats: InvokerStats) -> None:

@@ -37,8 +37,13 @@ import time
 from collections import OrderedDict
 from typing import Callable, Dict, Iterable, List, Optional
 
+from repro import obs
 from repro.storage.kvcache import StateCache
-from repro.storage.tiers import TierStats, tier_accounting
+from repro.storage.tiers import (
+    TierStats,
+    tier_accounting,
+    tier_accounting_capture,
+)
 
 __all__ = ["CommitTicket", "GroupCommitter", "StateJournal"]
 
@@ -118,9 +123,10 @@ class CommitTicket:
     ``put_many``).  ``add_done_callback`` runs the callback on the flusher
     thread, or inline if already resolved; each registered callback runs
     exactly once regardless of the registration/resolution race.
+    ``round`` is the number of pairs in the flush round that settled it.
     """
 
-    __slots__ = ("_done", "error", "_callbacks")
+    __slots__ = ("_done", "error", "_callbacks", "round")
 
     def __init__(self) -> None:
         # No Event allocated up front: the warm path resolves tickets via
@@ -129,6 +135,7 @@ class CommitTicket:
         self._done = False
         self.error: Optional[BaseException] = None
         self._callbacks: List[Callable[["CommitTicket"], None]] = []
+        self.round = 0
 
     def done(self) -> bool:
         return self._done
@@ -168,7 +175,7 @@ class CommitTicket:
 
 class _PendingCommit:
     __slots__ = ("blob_key", "blob", "entry_id", "meta", "tickets",
-                 "on_durable")
+                 "on_durable", "rids")
 
     def __init__(self, blob_key: str) -> None:
         self.blob_key = blob_key
@@ -177,6 +184,8 @@ class _PendingCommit:
         self.meta: Optional[dict] = None
         self.tickets: List[CommitTicket] = []
         self.on_durable: List[Callable[[], None]] = []
+        #: the requests (``obs``) whose commits this pair carries
+        self.rids: List[int] = []
 
 
 class GroupCommitter:
@@ -236,6 +245,7 @@ class GroupCommitter:
     ) -> CommitTicket:
         """Queue one blob (+ its journal marker) for the next flush."""
         ticket = CommitTicket()
+        rid = obs.request()
         with self._lock:
             if self._closed:
                 raise RuntimeError("group committer is closed")
@@ -247,6 +257,8 @@ class GroupCommitter:
             pc.entry_id = entry_id
             pc.meta = meta
             pc.tickets.append(ticket)
+            if rid is not None:
+                pc.rids.append(rid)
             if on_durable is not None:
                 pc.on_durable.append(on_durable)
         self._wake.set()
@@ -317,10 +329,16 @@ class GroupCommitter:
                     pc.meta or {}
                 ).encode()
         err: Optional[BaseException] = None
-        try:
-            self.journal.cache.put_many(batch)
-        except BaseException as exc:
-            err = exc
+        with obs.span(
+            "journal.flush", entries=len(drained),
+            bytes=sum(len(v) for v in batch.values()),
+            rids=tuple(r for pc in drained for r in pc.rids),
+        ) as attrs, tier_accounting_capture() as io:
+            try:
+                self.journal.cache.put_many(batch)
+            except BaseException as exc:
+                err = exc
+            attrs["syncs"] = io.syncs
         self.batches += 1
         self.entries += len(drained)
         for pc in drained:
@@ -331,4 +349,5 @@ class GroupCommitter:
                     except Exception:
                         pass  # bookkeeping must not kill the flusher
             for t in pc.tickets:
+                t.round = len(drained)
                 t._resolve(err)

@@ -54,11 +54,13 @@ from __future__ import annotations
 import itertools
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, Optional, Sequence, Tuple
 
 import jax
 
+from repro import obs
 from repro.core.journal import CommitTicket, GroupCommitter, StateJournal
 from repro.storage import serde
 from repro.storage.kvcache import StateCache
@@ -211,7 +213,10 @@ class FunctionRuntime:
     central trade.  ``group_commit=True`` starts a
     :class:`~repro.core.journal.GroupCommitter` so gateway-dispatched
     invocations batch their commits (call :meth:`close` to drain it).
+    ``log`` keeps the :data:`LOG_RECORDS` most recent invocation records.
     """
+
+    LOG_RECORDS = 4096
 
     def __init__(
         self,
@@ -223,7 +228,7 @@ class FunctionRuntime:
         self.cache = cache if cache is not None else StateCache()
         self.commit_every = max(1, commit_every)
         self.functions: Dict[str, StatefulFunction] = {}
-        self.log: list[InvocationRecord] = []
+        self.log: Deque[InvocationRecord] = deque(maxlen=self.LOG_RECORDS)
         #: same journal abstraction the MapReduce engine uses — commit
         #: markers ride the cache (durable iff the cache write-throughs).
         self.journal = StateJournal(self.cache, "fn")
@@ -362,40 +367,44 @@ class FunctionRuntime:
         slot.pending = 0
         if not slot.present or slot.version == slot.committed_version:
             return None  # nothing new to make durable — elide entirely
-        data = slot.codec.encode(slot.state, slot.version)
-        key = self._state_key(fn_name, session)
-        v = slot.version
-        last = slot.last_seq
-        if self._committer is not None:
-            def on_durable() -> None:
-                # Lock-free monotonic raise (the flusher thread must not
-                # block on a slot lock a waiting evictor holds); a stale
-                # read can only leave the stamp low, which at worst costs
-                # one redundant re-commit, never a lost write.
-                if v > slot.committed_version:
-                    slot.committed_version = v
+        with obs.span("runtime.commit") as attrs:
+            data = slot.codec.encode(slot.state, slot.version)
+            attrs["bytes"] = len(data)
+            key = self._state_key(fn_name, session)
+            v = slot.version
+            last = slot.last_seq
+            if self._committer is not None:
+                def on_durable() -> None:
+                    # Lock-free monotonic raise (the flusher thread must
+                    # not block on a slot lock a waiting evictor holds); a
+                    # stale read can only leave the stamp low, which at
+                    # worst costs one redundant re-commit, never a lost
+                    # write.
+                    if v > slot.committed_version:
+                        slot.committed_version = v
 
-            ticket = self._committer.enqueue(
-                key, data,
-                entry_id=f"{session}/{fn_name}" if last is not None else None,
-                meta={"seq": last} if last is not None else None,
-                on_durable=on_durable,
-            )
-            if defer:
-                return ticket
-            ticket.wait()
+                ticket = self._committer.enqueue(
+                    key, data,
+                    entry_id=(f"{session}/{fn_name}" if last is not None
+                              else None),
+                    meta={"seq": last} if last is not None else None,
+                    on_durable=on_durable,
+                )
+                if defer:
+                    return ticket
+                ticket.wait()
+                return None
+            # Sequential path: identical op sequence to unbatched commits —
+            # put(blob) then put(marker), marker strictly after its blob.
+            self.cache.put_versioned(key, data, v)
+            if last is not None:
+                # Stamp the seq this fn's state actually reflects (its own
+                # last invocation) — not the session-wide counter, which
+                # may include later invocations of *other* functions whose
+                # state is not yet durable.
+                self.journal.commit(f"{session}/{fn_name}", {"seq": last})
+            slot.committed_version = v
             return None
-        # Sequential path: identical op sequence to unbatched commits —
-        # put(blob) then put(marker), marker strictly after its blob.
-        self.cache.put_versioned(key, data, v)
-        if last is not None:
-            # Stamp the seq this fn's state actually reflects (its own last
-            # invocation) — not the session-wide counter, which may include
-            # later invocations of *other* functions whose state is not yet
-            # durable.
-            self.journal.commit(f"{session}/{fn_name}", {"seq": last})
-        slot.committed_version = v
-        return None
 
     def commit_all(self) -> None:
         for fn_name, session in list(self._slots.keys()):
@@ -471,7 +480,7 @@ class FunctionRuntime:
         # state transitions are linearizable per slot, while other
         # sessions (other slots) execute fully in parallel — the warm
         # path touches no global lock.
-        with slot.lock:
+        with slot.lock, obs.span("runtime.invoke", fn=fn.name) as attrs:
             state, cold, warm = self._load_state(
                 fn, slot, session, init_kwargs or {}
             )
@@ -481,6 +490,7 @@ class FunctionRuntime:
                 new_state = new_state.collapse()
             seq = sess.next_seq()
             slot.last_seq = seq
+            attrs.update(seq=seq, cold=cold)
             if new_state is not state:
                 slot.state = new_state
                 slot.version = next(self._version_clock)
@@ -495,7 +505,7 @@ class FunctionRuntime:
                 fn.name, session, seq, time.perf_counter() - t0, cold,
                 warm=warm, invoker=invoker, commit_ticket=ticket,
             )
-            self.log.append(record)  # list.append is GIL-atomic
+            self.log.append(record)  # deque.append is GIL-atomic
         return outputs, record
 
     def invoke_batch_with_records(
@@ -504,6 +514,7 @@ class FunctionRuntime:
         session: str,
         requests: List[Tuple[Optional[dict], dict]],
         invoker: str = "",
+        rids: Optional[Sequence[int]] = None,
     ) -> List[Tuple[Any, Optional[InvocationRecord],
                     Optional[BaseException]]]:
         """Run several queued invocations of one session back-to-back
@@ -524,6 +535,7 @@ class FunctionRuntime:
         Callers must only batch when ``commit_every == 1`` (the gateway's
         guard): with a larger cadence, a mid-batch threshold crossing
         would commit at a different point than sequential execution.
+        ``rids`` names each request's span (``obs``).
         """
         fn = self.functions[fn_name]
         sess = self._sessions.get(session)
@@ -535,20 +547,24 @@ class FunctionRuntime:
         ] = []
         records: List[InvocationRecord] = []
         with slot.lock:
-            for init_kwargs, inputs in requests:
+            for i, (init_kwargs, inputs) in enumerate(requests):
                 t0 = time.perf_counter()
                 try:
-                    state, cold, warm = self._load_state(
-                        fn, slot, session, init_kwargs or {}
-                    )
-                    step_state = (
-                        serde.CowState(state) if fn.cow else state
-                    )
-                    new_state, outputs = fn.compiled_step()(
-                        step_state, **inputs
-                    )
-                    if fn.cow and isinstance(new_state, serde.CowState):
-                        new_state = new_state.collapse()
+                    with obs.span("runtime.invoke",
+                                  rid=rids[i] if rids else None,
+                                  fn=fn.name) as attrs:
+                        state, cold, warm = self._load_state(
+                            fn, slot, session, init_kwargs or {}
+                        )
+                        step_state = (
+                            serde.CowState(state) if fn.cow else state
+                        )
+                        new_state, outputs = fn.compiled_step()(
+                            step_state, **inputs
+                        )
+                        if fn.cow and isinstance(new_state, serde.CowState):
+                            new_state = new_state.collapse()
+                        attrs["cold"] = cold
                 except Exception as exc:
                     results.append((None, None, exc))
                     continue

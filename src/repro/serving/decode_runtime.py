@@ -23,6 +23,7 @@ from typing import Any, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.stateful import StatefulFunction
 from repro.models import (
     ShapeConfig,
@@ -83,9 +84,13 @@ class PagedDecoder:
         # this process never ran the prefill (post-restart resume).
         _, self._treedef = flatten_cache(init_cache(cfg, 1, 2))
         self._prefill = jax.jit(self._prefill_fn)
-        self._decode = jax.jit(
-            lambda p, tok, cache, t: decode_step(p, cfg, tok, cache, t)
-        )
+
+        # named, so that its XLA module is ``jit_paged_decode_step`` and a
+        # trace tells it from any other jitted function
+        def paged_decode_step(p, tok, cache, t):
+            return decode_step(p, cfg, tok, cache, t)
+
+        self._decode = jax.jit(paged_decode_step)
         self.fn = StatefulFunction(name, self._step, init=self._init,
                                    jit=False)
 
@@ -105,9 +110,10 @@ class PagedDecoder:
         return tok.astype(jnp.int32)[:, None], kv
 
     def _init(self, session: str, prompt: jnp.ndarray) -> dict:
-        tok, kv = self._prefill(self.params, prompt)
-        layers, _ = flatten_cache(kv)
-        self.pager.create(session, layers, int(prompt.shape[1]) - 1)
+        with obs.span("serve.prefill", tokens=int(prompt.shape[1])):
+            tok, kv = self._prefill(self.params, prompt)
+            layers, _ = flatten_cache(kv)
+            self.pager.create(session, layers, int(prompt.shape[1]) - 1)
         return {"session": session,
                 "t": jnp.int32(int(prompt.shape[1]) - 1),
                 "tok": tok}
@@ -115,11 +121,17 @@ class PagedDecoder:
     # -- one decode token ---------------------------------------------------
     def _step(self, state: dict) -> Tuple[dict, jnp.ndarray]:
         sid = state["session"]
-        layers, _t_meta = self.pager.load(sid)
-        cache = unflatten_cache(self._treedef, layers)
-        t = jnp.int32(state["t"]) + 1
-        logits, new_cache = self._decode(self.params, state["tok"], cache, t)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-        new_layers, _ = flatten_cache(new_cache)
-        self.pager.write(sid, new_layers, int(t))
+        with obs.span("serve.decode") as attrs:
+            pos = attrs["position"] = int(state["t"]) + 1
+            t = jnp.int32(pos)
+            layers, _t_meta = self.pager.load(sid)
+            cache = unflatten_cache(self._treedef, layers)
+            logits, new_cache = self._decode(self.params, state["tok"], cache, t)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+            # the write-back copies the new cache to the host, which waits
+            # for the step anyway: the wait is timed on its own here
+            with obs.span("serve.device_wait"):
+                tok.block_until_ready()
+            new_layers, _ = flatten_cache(new_cache)
+            self.pager.write(sid, new_layers, pos)
         return {"session": sid, "t": t, "tok": tok}, tok

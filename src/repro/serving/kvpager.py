@@ -37,6 +37,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models.attention import AttnCache
 from repro.models.quant_cache import QuantAttnCache, quantize_kv
 from repro.storage import serde
@@ -253,7 +254,7 @@ class KVPager:
         """Install a freshly prefilled session: pin its prefix hot and
         write every block (the prefill result)."""
         ent = self._entry(sid, create=True)
-        with ent.lock:
+        with obs.span("pager.create") as attrs, ent.lock:
             ent.resident = list(layers)
             ent.t = int(t)
             ent.hot = True
@@ -264,7 +265,7 @@ class KVPager:
             pin = getattr(self.store, "pin", None)
             if pin is not None:
                 pin(self.session_prefix(sid))
-            self._write_blocks(ent, dirty=None)
+            attrs["blocks"], attrs["bytes"] = self._write_blocks(ent, dirty=None)
         self._note_resident_peak()
 
     def write(self, sid: str, layers: Sequence[Any], t: int) -> None:
@@ -272,7 +273,7 @@ class KVPager:
         decode step at position ``t`` touched (per layer — windowed
         layers wrap at their own ring size)."""
         ent = self._entry(sid)
-        with ent.lock:
+        with obs.span("pager.write", position=int(t)) as attrs, ent.lock:
             ent.resident = list(layers)
             ent.t = int(t)
             self._touch(ent)
@@ -284,33 +285,39 @@ class KVPager:
                 else:
                     slot = int(t) % _seq_len(layer)
                     dirty.add((li, slot // self.block_tokens))
-            self._write_blocks(ent, dirty=dirty)
+            attrs["blocks"], attrs["bytes"] = self._write_blocks(
+                ent, dirty=dirty)
 
     def _write_blocks(
         self, ent: _Session, dirty: Optional[set] = None
-    ) -> None:
+    ) -> Tuple[int, int]:
         """Serialize + put the selected (layer, block) blobs and the meta
-        record in one batched ``put_many``.  Caller holds ``ent.lock``."""
+        record in one batched ``put_many``.  Caller holds ``ent.lock``.
+        Returns the blocks and bytes written."""
         assert ent.resident is not None
         items: Dict[str, bytes] = {}
         meta_layers = []
-        for li, layer in enumerate(ent.resident):
-            kind = _layer_kind(layer)
-            if kind == _OPAQUE:
-                nb, S = 1, 0
-            else:
-                S = _seq_len(layer)
-                nb = -(-S // self.block_tokens)
-            meta_layers.append({"kind": kind, "S": S, "blocks": nb})
-            for b in range(nb):
-                if dirty is not None and (li, b) not in dirty:
-                    continue
-                lo = b * self.block_tokens
-                hi = min(S, lo + self.block_tokens) if kind != _OPAQUE else 0
-                blob = serde.dumps(_slice_block(layer, lo, hi))
-                items[self._block_key(ent.sid, li, b)] = blob
-                if kind == _QUANT:
-                    self.stats.quantized_blocks += 1
+        # slicing, the copy to the host and the encoding
+        with obs.span("pager.serialize") as attrs:
+            for li, layer in enumerate(ent.resident):
+                kind = _layer_kind(layer)
+                if kind == _OPAQUE:
+                    nb, S = 1, 0
+                else:
+                    S = _seq_len(layer)
+                    nb = -(-S // self.block_tokens)
+                meta_layers.append({"kind": kind, "S": S, "blocks": nb})
+                for b in range(nb):
+                    if dirty is not None and (li, b) not in dirty:
+                        continue
+                    lo = b * self.block_tokens
+                    hi = min(S, lo + self.block_tokens) if kind != _OPAQUE else 0
+                    blob = serde.dumps(_slice_block(layer, lo, hi))
+                    items[self._block_key(ent.sid, li, b)] = blob
+                    if kind == _QUANT:
+                        self.stats.quantized_blocks += 1
+            attrs["blocks"] = len(items)
+            attrs["bytes"] = sum(len(b) for b in items.values())
         meta = {
             "t": ent.t,
             "quantized": ent.quantized,
@@ -322,6 +329,7 @@ class KVPager:
         for key, blob in items.items():
             ent.sizes[key] = len(blob)
         self.stats.blocks_written += len(items) - 1
+        return attrs["blocks"], attrs["bytes"]
 
     # -- read path ----------------------------------------------------------
     def load(self, sid: str) -> Tuple[List[Any], int]:
@@ -335,17 +343,18 @@ class KVPager:
                 ent = self._entry(sid)
             else:
                 raise
-        with ent.lock:
+        with obs.span("pager.load", blocks=0) as attrs, ent.lock:
             if ent.resident is None:
                 if not ent.hot:
                     self.stats.demand_faults += 1
                     self.resume(sid, prefetch=False)
-                self._assemble(ent)
+                attrs["blocks"] = self._assemble(ent)
             self._touch(ent)
             assert ent.resident is not None
             return list(ent.resident), ent.t
 
-    def _assemble(self, ent: _Session) -> None:
+    def _assemble(self, ent: _Session) -> int:
+        """Read every block of the session back; returns their count."""
         meta = json.loads(self.store.get(self._meta_key(ent.sid)))
         layers: List[Any] = []
         for li, info in enumerate(meta["layers"]):
@@ -357,6 +366,7 @@ class KVPager:
         ent.resident = layers
         ent.t = int(meta["t"])
         ent.quantized = bool(meta["quantized"])
+        return sum(info["blocks"] for info in meta["layers"])
 
     # -- placement transitions ----------------------------------------------
     def demote(self, sid: str) -> bool:
@@ -368,7 +378,7 @@ class KVPager:
             ent = self._entry(sid)
         except KeyError:
             return False
-        with ent.lock:
+        with obs.span("pager.demote", blocks=0) as attrs, ent.lock:
             if not ent.hot:
                 return False
             if not self.lossless and not ent.quantized:
@@ -390,6 +400,7 @@ class KVPager:
             if demoter is not None:
                 for key in list(ent.sizes):
                     demoter(key)
+                attrs["blocks"] = len(ent.sizes)
             ent.resident = None
             ent.hot = False
             self.stats.demotions += 1
@@ -408,7 +419,7 @@ class KVPager:
             if not self.adopt(sid):
                 raise
             ent = self._entry(sid)
-        with ent.lock:
+        with obs.span("pager.resume") as attrs, ent.lock:
             if ent.hot:
                 return False
             pin = getattr(self.store, "pin", None)
@@ -423,7 +434,8 @@ class KVPager:
             if prefetch:
                 promote = getattr(self.store, "promote_async", None)
                 if promote is not None:
-                    promote(list(self.store.keys(self.session_prefix(sid))))
+                    attrs["blocks"] = promote(
+                        list(self.store.keys(self.session_prefix(sid))))
         self._note_resident_peak()
         return True
 

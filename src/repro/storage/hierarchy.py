@@ -60,6 +60,7 @@ from typing import (
     Union,
 )
 
+from repro import obs
 from repro.storage.tiers import (
     DramTier,
     Tier,
@@ -361,16 +362,27 @@ class TieredStore(Tier):
             self.stats.modeled_seconds += modeled
 
     # -- Tier protocol ------------------------------------------------------
+    def _acquire(self) -> None:
+        """Take the placement lock; a wait for another thread's hold is
+        the span ``tier.lock_wait``."""
+        if not self._mutex.acquire(blocking=False):
+            with obs.span("tier.lock_wait", store=self.name):
+                self._mutex.acquire()
+
     def put(self, key: str, value: bytes) -> None:
         t0 = time.perf_counter()
-        with tier_accounting_capture() as inline:
-            with self._mutex:
+        with obs.span("tier.put", keys=1, bytes=len(value)), \
+                tier_accounting_capture() as inline:
+            self._acquire()
+            try:
                 self._install(key, value)
                 self._journal_put({key: value})
                 if not self.policy.write_back:
                     self._write_home(key, value)
                 else:
                     self._dirty[key] = self._entries[key].version
+            finally:
+                self._mutex.release()
         if self.policy.write_back:
             self._wake.set()
         self._logical_write(len(value), time.perf_counter() - t0,
@@ -381,8 +393,11 @@ class TieredStore(Tier):
         if not items:
             return
         t0 = time.perf_counter()
-        with tier_accounting_capture() as inline:
-            with self._mutex:
+        total = sum(len(v) for v in items.values())
+        with obs.span("tier.put", keys=len(items), bytes=total), \
+                tier_accounting_capture() as inline:
+            self._acquire()
+            try:
                 for key, value in items.items():
                     self._install(key, value)
                 self._journal_put(items)
@@ -396,9 +411,10 @@ class TieredStore(Tier):
                 else:
                     for key in items:
                         self._dirty[key] = self._entries[key].version
+            finally:
+                self._mutex.release()
         if self.policy.write_back:
             self._wake.set()
-        total = sum(len(v) for v in items.values())
         self._logical_write(total, time.perf_counter() - t0,
                             inline.modeled_seconds, ops=len(items))
         for key in items:
@@ -436,18 +452,24 @@ class TieredStore(Tier):
         # Redo blobs first, then their markers: a torn journal batch can
         # leave orphan blobs (garbage, harmless) but never a marker whose
         # blob is missing — recovery skips markers without blobs anyway.
-        self._journal_cache.put_many(
-            {self._data_key(k): v for k, v in items.items()}
-        )
-        self._journal.commit_many(
-            {k: {"bytes": len(v), "seq": self._entries[k].version}
-             for k, v in items.items()}
-        )
+        with obs.span("tier.journal_put", keys=len(items),
+                      bytes=sum(len(v) for v in items.values())) as attrs, \
+                tier_accounting_capture() as io:
+            self._journal_cache.put_many(
+                {self._data_key(k): v for k, v in items.items()}
+            )
+            self._journal.commit_many(
+                {k: {"bytes": len(v), "seq": self._entries[k].version}
+                 for k, v in items.items()}
+            )
+            attrs["syncs"] = io.syncs
 
     def get(self, key: str) -> bytes:
         t0 = time.perf_counter()
-        with tier_accounting_capture() as inline:
-            with self._mutex:
+        with obs.span("tier.get", keys=1) as attrs, \
+                tier_accounting_capture() as inline:
+            self._acquire()
+            try:
                 ent = self._entries.get(key)
                 if ent is None:
                     ent = self._adopt(key)
@@ -465,6 +487,9 @@ class TieredStore(Tier):
                     # first read after a crash re-adopts them straight
                     # into the fast level.
                     self._promote_locked(key, value)
+            finally:
+                self._mutex.release()
+            attrs["bytes"] = len(value)
         self._logical_read(len(value), time.perf_counter() - t0,
                            inline.modeled_seconds)
         return value

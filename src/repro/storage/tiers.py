@@ -74,6 +74,8 @@ class TierStats:
     modeled_seconds: float = 0.0
     #: Wall-clock seconds actually spent inside tier calls.
     wall_seconds: float = 0.0
+    #: ``fsync`` calls issued (persistence points of a real device).
+    syncs: int = 0
 
     def merge(self, other: "TierStats") -> "TierStats":
         return TierStats(
@@ -83,6 +85,7 @@ class TierStats:
             self.write_ops + other.write_ops,
             self.modeled_seconds + other.modeled_seconds,
             self.wall_seconds + other.wall_seconds,
+            self.syncs + other.syncs,
         )
 
     def merge_into(self, other: "TierStats") -> None:
@@ -94,6 +97,7 @@ class TierStats:
         self.write_ops += other.write_ops
         self.modeled_seconds += other.modeled_seconds
         self.wall_seconds += other.wall_seconds
+        self.syncs += other.syncs
 
 
 #: Thread-local accounting scope.  Tier stats are global per tier; a
@@ -261,18 +265,21 @@ class Tier:
             scoped.wall_seconds += wall
             scoped.modeled_seconds += modeled
 
-    def _account_write(self, nbytes: int, wall: float, modeled: float = 0.0) -> None:
+    def _account_write(self, nbytes: int, wall: float, modeled: float = 0.0,
+                       syncs: int = 0) -> None:
         with self._lock:
             self.stats.bytes_written += nbytes
             self.stats.write_ops += 1
             self.stats.wall_seconds += wall
             self.stats.modeled_seconds += modeled
+            self.stats.syncs += syncs
         scoped = _scoped_stats()
         if scoped is not None:
             scoped.bytes_written += nbytes
             scoped.write_ops += 1
             scoped.wall_seconds += wall
             scoped.modeled_seconds += modeled
+            scoped.syncs += syncs
 
 
 class DramTier(Tier):
@@ -389,7 +396,7 @@ class PmemTier(Tier):
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, path)  # atomic publish
-        self._account_write(len(value), time.perf_counter() - t0)
+        self._account_write(len(value), time.perf_counter() - t0, syncs=1)
         self._notify(key)
 
     def get(self, key: str) -> bytes:
