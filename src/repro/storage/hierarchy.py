@@ -19,7 +19,9 @@ data between them according to a :class:`PlacementPolicy`:
     :class:`~repro.core.journal.StateJournal`: when the journal rides a
     durable cache, an acknowledged put survives any crash/torn-flush
     schedule (the flusher only clears a dirty record after the home write
-    of that exact version succeeded);
+    of that exact version succeeded).  A caller that has already made its
+    puts durable elsewhere (a cache with a persistent write-through tier)
+    hands them to ``install_many``, which writes no redo record;
   * **prefetch** — ``prefetch(prefix)`` subscribes to the home (or an
     explicit source) tier's ``watch()`` events and pulls matching keys
     into the fast level in the background, so shuffle partitions
@@ -171,6 +173,9 @@ class TieredStore(Tier):
         ]
         self._used: List[int] = [0 for _ in self.levels]
         self._dirty: Dict[str, int] = {}  # key -> version awaiting flush
+        #: keys with a redo record in the journal (volatile: ``recover``
+        #: rebuilds it from the journal).
+        self._redo: set = set()
         #: pinned key prefixes: matching keys are held in the fast level —
         #: never demotion victims, promoted on first read (see :meth:`pin`).
         self._pins: set = set()
@@ -196,6 +201,8 @@ class TieredStore(Tier):
         self._journal_cache = journal
         self.promotions = 0
         self.demotions = 0
+        #: keys put through :meth:`install_many`, with no redo record.
+        self.unjournaled_puts = 0
         self.flush_errors = 0
         self._hits: List[int] = [0 for _ in self.levels]
         self._closed = False
@@ -341,9 +348,14 @@ class TieredStore(Tier):
     def _clear_dirty(self, key: str, version: int) -> None:
         if self._dirty.get(key) == version:
             del self._dirty[key]
-            if self._journal is not None:
-                self._journal.retract(key)
-                self._journal_cache.delete(self._data_key(key))
+            self._retract(key)
+
+    def _retract(self, key: str) -> None:
+        """Drop ``key``'s redo record, if it has one."""
+        if key in self._redo:
+            self._redo.discard(key)
+            self._journal.retract(key)
+            self._journal_cache.delete(self._data_key(key))
 
     # -- logical accounting -------------------------------------------------
     def _logical_read(self, nbytes: int, wall: float, modeled: float) -> None:
@@ -390,6 +402,18 @@ class TieredStore(Tier):
         self._notify(key)
 
     def put_many(self, items: Mapping[str, bytes]) -> None:
+        self._put_many(items, redo=True)
+
+    def install_many(self, items: Mapping[str, bytes]) -> None:
+        """Put ``items`` that the caller has already made durable (a
+        :class:`~repro.storage.kvcache.StateCache` whose write-through
+        tier is persistent): the placement and home flush of
+        :meth:`put_many`, without a redo record — the caller's durable
+        copy is what recovery reloads.  An older redo record of such a
+        key is retracted, so replay never brings back a stale value."""
+        self._put_many(items, redo=False)
+
+    def _put_many(self, items: Mapping[str, bytes], redo: bool) -> None:
         if not items:
             return
         t0 = time.perf_counter()
@@ -400,7 +424,12 @@ class TieredStore(Tier):
             try:
                 for key, value in items.items():
                     self._install(key, value)
-                self._journal_put(items)
+                if redo:
+                    self._journal_put(items)
+                else:
+                    for key in items:
+                        self._retract(key)
+                    self.unjournaled_puts += len(items)
                 if not self.policy.write_back:
                     # Same single-level guard as _write_home: on a
                     # one-level store _install already wrote the values.
@@ -452,6 +481,7 @@ class TieredStore(Tier):
         # Redo blobs first, then their markers: a torn journal batch can
         # leave orphan blobs (garbage, harmless) but never a marker whose
         # blob is missing — recovery skips markers without blobs anyway.
+        self._redo.update(items)
         with obs.span("tier.journal_put", keys=len(items),
                       bytes=sum(len(v) for v in items.values())) as attrs, \
                 tier_accounting_capture() as io:
@@ -505,8 +535,8 @@ class TieredStore(Tier):
             ent = self._entries.pop(key, None)
             if ent is not None:
                 self._drop_from_level(key, ent.level, ent.size)
-                self._clear_dirty(key, ent.version)
             self._dirty.pop(key, None)
+            self._retract(key)
             for lv in self.levels:
                 lv.tier.delete(key)
 
@@ -809,6 +839,7 @@ class TieredStore(Tier):
                     lv.tier.clear()
             self._entries.clear()
             self._dirty.clear()
+            self._redo.clear()
             self._inflight_flush.clear()
             for lru in self._lru:
                 lru.clear()
@@ -842,6 +873,7 @@ class TieredStore(Tier):
                 self._install(key, value)
                 self._entries[key].version = int(meta.get("seq", 1))
                 self._dirty[key] = self._entries[key].version
+                self._redo.add(key)
                 replayed += 1
         if replayed:
             self._wake.set()

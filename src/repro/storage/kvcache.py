@@ -21,6 +21,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Mapping, Optional
 
+from repro.storage.hierarchy import TieredStore
 from repro.storage.tiers import DramTier, Tier, WatchRegistry
 
 __all__ = ["StateCache"]
@@ -40,8 +41,15 @@ class StateCache:
 
     ``write_through=None`` reproduces stock Marvel (volatile Ignite).
     Passing a persistent tier gives the checkpoint-capable variant: every
-    put lands in DRAM *and* the persistent tier, and ``recover()`` reloads
+    put lands in the persistent tier *and* DRAM, and ``recover()`` reloads
     the DRAM view after a (simulated) crash.
+
+    A put is made durable once, by whoever owns durability: the
+    write-through copy is written first, then the memory tier's.  When
+    the write-through tier is persistent and the memory tier is a
+    :class:`~repro.storage.hierarchy.TieredStore`, the store takes the
+    put through ``install_many``, without a write-back redo record of its
+    own: the write-through copy is what ``recover()`` reloads.
 
     Thread-safety: safe for concurrent use by many invokers.  Individual
     ops are atomic (tiers lock internally; TTL bookkeeping is under the
@@ -58,6 +66,12 @@ class StateCache:
     ) -> None:
         self.memory = memory if memory is not None else DramTier()
         self.write_through = write_through
+        self._install: Optional[Callable[[Mapping[str, bytes]], None]] = (
+            memory.install_many
+            if isinstance(memory, TieredStore)
+            and write_through is not None and write_through.persistent
+            else None
+        )
         self._ttl: Dict[str, float] = {}
         #: key -> version stamp of the blob last stored via
         #: :meth:`put_versioned` (volatile; cleared on ``crash``).
@@ -67,27 +81,38 @@ class StateCache:
 
     # -- basic KV -----------------------------------------------------------
     def put(self, key: str, value: bytes, ttl: Optional[float] = None) -> None:
-        self.memory.put(key, value)
+        if self.write_through is not None:
+            self.write_through.put(key, value)
+        self._hold(key, value)
         with self._lock:
             if ttl is not None:
                 self._ttl[key] = time.monotonic() + ttl
             self._versions.pop(key, None)  # overwrite invalidates the memo
-        if self.write_through is not None:
-            self.write_through.put(key, value)
         self._notify(key)
 
     def put_many(self, items: Mapping[str, bytes]) -> None:
         """Batched put: one request to each tier for the whole batch (the
         tiers charge a single modeled latency — see ``Tier.put_many``)."""
-        self.memory.put_many(items)
+        if self.write_through is not None:
+            self.write_through.put_many(items)
+        if self._install is not None:
+            self._install(items)
+        else:
+            self.memory.put_many(items)
         with self._lock:
             for key in items:  # overwrite kills any stale TTL / version memo
                 self._ttl.pop(key, None)
                 self._versions.pop(key, None)
-        if self.write_through is not None:
-            self.write_through.put_many(items)
         for key in items:
             self._notify(key)
+
+    def _hold(self, key: str, value: bytes) -> None:
+        """Land one value, already durable if it will ever be, in the
+        memory tier."""
+        if self._install is not None:
+            self._install({key: value})
+        else:
+            self.memory.put(key, value)
 
     def put_versioned(self, key: str, value: bytes, version: int) -> bool:
         """Put ``value`` unless this exact ``version`` of ``key`` was
@@ -135,7 +160,7 @@ class StateCache:
         # Demand-fault from the persistent tier (crash recovery path).
         if self.write_through is not None and self.write_through.contains(key):
             value = self.write_through.get(key)
-            self.memory.put(key, value)
+            self._hold(key, value)
             return value
         raise KeyError(key)
 
@@ -213,7 +238,7 @@ class StateCache:
         if self.write_through is None:
             return n
         for k in self.write_through.keys():
-            self.memory.put(k, self.write_through.get(k))
+            self._hold(k, self.write_through.get(k))
             n += 1
         return n
 

@@ -5,7 +5,11 @@ import time
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
+from repro.api import ClusterConfig, MarvelClient, TierSpec
 from repro.core import FunctionRuntime, Scheduler, Task, TaskFailedError
+from repro.models.attention import AttnCache
+from repro.serving import KVPager
 from repro.storage import DramTier, PmemTier, StateCache
 
 
@@ -115,6 +119,60 @@ def test_session_seq_resumes_from_journal_after_crash(tmp_path):
     assert rt.session("b").seq == 1
     rt.invoke("counter", session="a", x=jnp.int32(1))
     assert rt.log[-1].seq == 3 and rt.log[-1].session == "a"
+
+
+# -- a group commit made durable once ----------------------------------------
+
+def _pmem_client(tmp_path):
+    """Runtime state on a write-back DRAM→PMEM hierarchy with a PMEM
+    journal: the stack ``MarvelClient`` builds for serving."""
+    client = MarvelClient(ClusterConfig(
+        name="once",
+        tiers=(TierSpec("dram", capacity_bytes=1 << 20),
+               TierSpec("pmem", path=str(tmp_path / "home"))),
+        invokers=1, commit_every=1, journal="pmem",
+        journal_path=str(tmp_path / "journal")))
+    client.function("counter", init=lambda: 0, jit=False)(
+        lambda s, x: (s + x, s + x))
+    return client
+
+
+def test_a_group_commit_round_is_made_durable_once(tmp_path):
+    """A one-entry round writes its blob and marker to the PMEM journal,
+    one fsync each, and hands them to the hierarchy without a redo
+    record of its own."""
+    with _pmem_client(tmp_path) as client:
+        client.invoke("counter", session="a", x=1)  # the cold start
+        durable = client.runtime.cache.write_through
+        syncs = durable.stats.syncs
+        t = time.perf_counter()
+        assert client.invoke("counter", session="a", x=1) == 2  # acked
+        flush = [s for s in obs.spans(t) if s.name == "journal.flush"]
+        assert len(flush) == 1
+        assert flush[0].attrs["entries"] == 1
+        assert flush[0].attrs["syncs"] == 2
+        assert not [s for s in obs.spans(t) if s.name == "tier.journal_put"]
+        assert durable.stats.syncs - syncs == 2
+        assert client.state.unjournaled_puts == 4  # two rounds of two keys
+        assert client.state._journal.entries() == {}
+
+
+def test_a_pager_write_on_the_same_store_keeps_its_redo_record(tmp_path):
+    """The pager's blocks go to the hierarchy alone, so they keep the
+    write-back redo log: a redo blob and a marker a key, one fsync each."""
+    layers = [AttnCache(k=jnp.full((1, 8, 2, 4), float(i)),
+                        v=jnp.full((1, 8, 2, 4), -float(i)))
+              for i in range(2)]
+    with _pmem_client(tmp_path) as client:
+        pager = KVPager(client.state, block_tokens=4, lossless=True)
+        pager.create("c", layers, t=0)
+        t = time.perf_counter()
+        pager.write("c", layers, t=5)
+        puts = [s for s in obs.spans(t) if s.name == "tier.journal_put"]
+        assert len(puts) == 1
+        # a block of each of the two layers, and the meta record
+        assert puts[0].attrs["keys"] == 3 and puts[0].attrs["syncs"] == 6
+        assert client.state.unjournaled_puts == 0
 
 
 # -- scheduler ---------------------------------------------------------------

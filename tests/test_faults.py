@@ -412,3 +412,77 @@ def test_serde_state_roundtrip_is_byte_identical(tmp_path):
     restored = serde.loads(blob)
     assert isinstance(restored["cache"], AttnCache)
     assert serde.dumps(restored) == blob
+
+
+# -- a commit made durable once -------------------------------------------------
+
+def test_acked_group_commit_survives_a_crash_without_a_redo_record(tmp_path):
+    """The runtime's state rides a write-back DRAM→PMEM hierarchy under a
+    persistent write-through: an acked group commit is durable in the
+    write-through alone.  The home flush never lands (every one tears)
+    and no redo record is written, yet a crash loses nothing acked."""
+    from repro.api import ClusterConfig, FaultSpec, MarvelClient, TierSpec
+
+    cfg = ClusterConfig(
+        name="once",
+        tiers=(TierSpec("dram", capacity_bytes=1 << 20),
+               TierSpec("pmem", path=str(tmp_path / "home"))),
+        invokers=1, commit_every=1, journal="pmem",
+        journal_path=str(tmp_path / "journal"),
+        faults=FaultSpec(torn_put_many_rate=1.0))
+    with MarvelClient(cfg) as client:
+        client.function("counter", init=lambda: 0, jit=False)(
+            lambda s, x: (s + x, s + x))
+        for _ in range(3):  # each returns once its commit is durable
+            client.invoke("counter", session="a", x=1)
+        rt, store = client.runtime, client.state
+        acked = rt.state_bytes("counter", "a")
+        assert STATE_KEY in store.dirty_keys  # the home flush never landed
+        assert store._journal.entries() == {}  # nor did any redo record
+        rt.crash()
+        store.levels[-1].tier.heal()
+        rt.recover()
+        assert rt.cache.get(STATE_KEY) == acked
+        assert rt.session("a").seq == 3
+        assert client.invoke("counter", session="a", x=1) == 4
+
+
+def test_torn_write_through_fails_the_ticket_and_replays_nothing(tmp_path):
+    """The write-through is written before the hierarchy sees the put:
+    a torn write-through fails the ticket with nothing newer in DRAM and
+    no redo record, so a crash brings back the last acked state, not
+    the failed one."""
+    from repro.storage import PlacementPolicy, TieredStore, TierLevel
+
+    # seed 1 tears the second round before either item lands
+    durable = FaultInjectingTier(PmemTier(str(tmp_path / "journal")),
+                                 seed=1, schedule=[("torn", 1)])
+    store = TieredStore(
+        [TierLevel("dram", DramTier(), None),
+         TierLevel("pmem", PmemTier(str(tmp_path / "home")))],
+        policy=PlacementPolicy(write_back=True, flush_interval=0.005),
+        journal=StateCache(write_through=durable), name="state")
+    rt = FunctionRuntime(cache=StateCache(memory=store, write_through=durable),
+                         group_commit=True)
+    rt.register(StatefulFunction(
+        "counter", lambda s, x: (s + x, s + x), init=lambda: 0, jit=False))
+    _, rec = rt.invoke_with_record("counter", session="a",
+                                   defer_commit=True, x=1)
+    rec.commit_ticket.wait(timeout=10)
+    acked = durable.get(STATE_KEY)
+    _, rec = rt.invoke_with_record("counter", session="a",
+                                   defer_commit=True, x=1)
+    with pytest.raises(TornWriteError):
+        rec.commit_ticket.wait(timeout=10)
+    assert durable.get(STATE_KEY) == acked  # nothing of the round landed
+    assert store.get(STATE_KEY) == acked  # nor reached DRAM
+    assert store.unjournaled_puts == 2  # the acked round's blob and marker
+    rt.crash()
+    durable.heal()
+    assert store._journal.entries() == {}
+    assert rt.recover() == 2  # the write-through's two keys, no replay
+    assert rt.cache.get(STATE_KEY) == acked
+    assert rt.session("a").seq == 1
+    assert rt.invoke("counter", session="a", x=1) == 2
+    rt.close()
+    store.close()
