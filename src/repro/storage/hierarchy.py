@@ -478,10 +478,10 @@ class TieredStore(Tier):
     def _journal_put(self, items: Mapping[str, bytes]) -> None:
         if self._journal is None or not self.policy.write_back:
             return
+        self._redo.update(items)
         # Redo blobs first, then their markers: a torn journal batch can
         # leave orphan blobs (garbage, harmless) but never a marker whose
         # blob is missing — recovery skips markers without blobs anyway.
-        self._redo.update(items)
         with obs.span("tier.journal_put", keys=len(items),
                       bytes=sum(len(v) for v in items.values())) as attrs, \
                 tier_accounting_capture() as io:
