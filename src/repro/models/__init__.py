@@ -10,6 +10,7 @@ from repro.models.config import (
     RGLRUConfig,
     SSMConfig,
     ShapeConfig,
+    YarnConfig,
     reduced_for_smoke,
 )
 from repro.models.param import (
@@ -36,6 +37,7 @@ __all__ = [
     "RGLRUConfig",
     "SSMConfig",
     "ShapeConfig",
+    "YarnConfig",
     "reduced_for_smoke",
     "ParamDef",
     "abstract_params",

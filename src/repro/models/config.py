@@ -19,6 +19,7 @@ __all__ = [
     "BlockSpec",
     "MoEConfig",
     "MLAConfig",
+    "YarnConfig",
     "SSMConfig",
     "RGLRUConfig",
     "ModelConfig",
@@ -32,9 +33,22 @@ class MoEConfig:
     top_k: int
     d_expert: int
     n_shared: int = 0
-    #: router softmax over all experts (deepseek) vs over top-k (dbrx-style)
+    #: renormalize the top-k softmax weights to sum to 1 (dbrx-style);
+    #: False keeps the softmax over all experts as it is (deepseek)
     normalize_top_k: bool = True
     capacity_factor: float = 1.25
+    #: the routed experts this layer holds, ``[first_held, first_held +
+    #: n_held)`` of ``n_experts`` (``None``: all of them).  The router keeps
+    #: all ``n_experts`` outputs and its ``top_k``; slots routed to an
+    #: expert held elsewhere add nothing here (one chip's share under
+    #: expert parallelism, run without the exchange)
+    first_held: int = 0
+    n_held: Optional[int] = None
+
+    @property
+    def held(self) -> int:
+        """How many routed experts this layer holds."""
+        return self.n_experts if self.n_held is None else self.n_held
 
 
 @dataclass(frozen=True)
@@ -44,6 +58,18 @@ class MLAConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     q_lora_rank: Optional[int] = None  # v2-lite projects q directly
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -99,6 +125,7 @@ class ModelConfig:
     embed_scale: bool = False  # gemma: embeddings * sqrt(d_model)
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    rope_scaling: Optional[YarnConfig] = None
     attn_softcap: Optional[float] = None
     final_softcap: Optional[float] = None
     post_block_norm: bool = False  # gemma2 pre+post norm sandwich

@@ -12,7 +12,7 @@ for CPU dry-runs and as the kernel oracle).
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +24,8 @@ __all__ = [
     "layer_norm",
     "softcap",
     "apply_rope",
+    "yarn_freqs",
+    "yarn_mscale",
     "chunked_attention",
     "decode_attention",
     "mlp_defs",
@@ -70,19 +72,58 @@ def rope_freqs(dh_rot: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, dh_rot, 2, dtype=jnp.float32) / dh_rot))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dh_rot: int, theta: float, yarn: Any) -> jax.Array:
+    """YaRN inverse frequencies (DeepSeek-V2): each of the ``dh_rot / 2``
+    frequencies blends the plain one and the one interpolated by
+    ``factor``, by a linear ramp between the dims whose wavelength turns
+    ``beta_fast`` and ``beta_slow`` times over ``original_max_position``;
+    the same at every position."""
+
+    def correction_dim(rotations: float) -> float:
+        return (dh_rot * math.log(yarn.original_max_position
+                                  / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dh_rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dh_rot // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = rope_freqs(dh_rot, theta)
+    return extra / yarn.factor * ramp + extra * (1.0 - ramp)
+
+
 def apply_rope(
     x: jax.Array,  # (..., T, H, Dh) or (..., H, Dh) with positions broadcast
     positions: jax.Array,  # (..., T) int32
     theta: float = 10000.0,
     dh_rot: Optional[int] = None,
+    scaling: Any = None,
 ) -> jax.Array:
-    """Rotary embedding on the first ``dh_rot`` head dims (rest pass through)."""
+    """Rotary embedding on the first ``dh_rot`` head dims (rest pass through).
+    ``scaling`` is a :class:`~repro.models.config.YarnConfig` or None."""
     dh = x.shape[-1]
     dh_rot = dh if dh_rot is None else dh_rot
-    freqs = rope_freqs(dh_rot, theta)  # (dh_rot/2,)
+    if scaling is None:
+        freqs = rope_freqs(dh_rot, theta)  # (dh_rot/2,)
+    else:
+        freqs = yarn_freqs(dh_rot, theta, scaling)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., T, dh_rot/2)
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        mag = (yarn_mscale(scaling.factor, scaling.mscale)
+               / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if mag != 1.0:
+            cos, sin = cos * mag, sin * mag
     xr = x[..., :dh_rot].astype(jnp.float32)
     x1, x2 = xr[..., : dh_rot // 2], xr[..., dh_rot // 2 :]
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

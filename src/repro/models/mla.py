@@ -18,7 +18,12 @@ import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
 from repro.models.ctx import ShardCtx, constrain
-from repro.models.layers import apply_rope, chunked_attention, rms_norm
+from repro.models.layers import (
+    apply_rope,
+    chunked_attention,
+    rms_norm,
+    yarn_mscale,
+)
 from repro.models.param import FSDP, TP, ParamDef
 
 __all__ = ["mla_defs", "mla_apply", "mla_decode", "init_mla_cache", "MLACache"]
@@ -39,8 +44,14 @@ def mla_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
 
 
 def _scale(cfg: ModelConfig) -> float:
+    """Softmax scale: ``1/sqrt(dq)``, times ``mscale^2`` under YaRN with
+    ``mscale_all_dim`` set (DeepSeek-V2)."""
     m = cfg.mla
-    return 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def mla_apply(
@@ -64,12 +75,12 @@ def mla_apply(
     pos = jnp.arange(T)[None, :]
     q = jnp.einsum("btd,dhk->bthk", x, p["wq"])
     q_nope, q_pe = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim :]
-    q_pe = apply_rope(q_pe, pos, cfg.rope_theta)
+    q_pe = apply_rope(q_pe, pos, cfg.rope_theta, scaling=cfg.rope_scaling)
 
     kv_a = x @ p["wkv_a"]  # (B, T, r + dr)
     c_kv = rms_norm(kv_a[..., : m.kv_lora_rank], p["kv_norm"])
     k_pe = kv_a[..., m.kv_lora_rank :][:, :, None, :]  # (B, T, 1, dr)
-    k_pe = apply_rope(k_pe, pos, cfg.rope_theta)
+    k_pe = apply_rope(k_pe, pos, cfg.rope_theta, scaling=cfg.rope_scaling)
 
     k_nope = jnp.einsum("btr,rhk->bthk", c_kv, p["wk_b"])
     v = jnp.einsum("btr,rhv->bthv", c_kv, p["wv_b"])
@@ -127,12 +138,14 @@ def mla_decode(
     pos = jnp.full((B, 1), t, jnp.int32)
     q = jnp.einsum("btd,dhk->bthk", x, p["wq"])[:, 0]  # (B, H, dq)
     q_nope, q_pe = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim :]
-    q_pe = apply_rope(q_pe[:, None], pos, cfg.rope_theta)[:, 0]
+    q_pe = apply_rope(q_pe[:, None], pos, cfg.rope_theta,
+                      scaling=cfg.rope_scaling)[:, 0]
 
     kv_a = (x @ p["wkv_a"])  # (B, 1, r + dr)
     c_kv_new = rms_norm(kv_a[..., : m.kv_lora_rank], p["kv_norm"])
     k_pe_new = apply_rope(
-        kv_a[..., m.kv_lora_rank :][:, :, None, :], pos, cfg.rope_theta
+        kv_a[..., m.kv_lora_rank :][:, :, None, :], pos, cfg.rope_theta,
+        scaling=cfg.rope_scaling,
     )[:, :, 0]
     c_kv = jax.lax.dynamic_update_slice_in_dim(cache.c_kv, c_kv_new, t, axis=1)
     k_pe = jax.lax.dynamic_update_slice_in_dim(cache.k_pe, k_pe_new, t, axis=1)

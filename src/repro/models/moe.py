@@ -6,10 +6,16 @@ like Marvel keeps MapReduce's mapper→reducer traffic in Ignite instead of
 S3.  The dispatch machinery mirrors ``core/device_shuffle.pack_buckets``
 (sort → capacity-pack → all_to_all → local compute → reverse path).
 
-Three apply paths:
+Four apply paths:
 
+  * ``moe_apply_held``    — one device: the layer holds the routed experts
+    ``[first_held, first_held + n_held)`` (all by default), routes over all
+    of them, and computes, with no token dropped, only what its own
+    experts give (tokens sorted by held expert, ``lax.ragged_dot``); on one
+    chip of an expert-parallel deployment it runs without the exchange.
   * ``moe_apply_dense``   — reference: every token through its top-k experts
-    via per-expert capacity gather; no mesh needed (smoke tests, oracle).
+    via per-expert capacity gather; no mesh needed (oracle of the two
+    sharded paths, which pack by capacity too).
   * ``moe_apply_a2a``     — shard_map EP: tokens sequence-sharded over TP,
     two all_to_alls (dispatch + return).  Used for train/prefill.
   * ``moe_apply_gather``  — shard_map EP for tiny T (decode): tokens
@@ -34,14 +40,14 @@ from repro.models.config import ModelConfig, MoEConfig
 from repro.models.layers import mlp_apply, mlp_defs
 from repro.models.param import FSDP, TP, ParamDef
 
-__all__ = ["moe_defs", "moe_apply"]
+__all__ = ["moe_defs", "moe_apply", "moe_apply_held"]
 
 
 def moe_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
     m = cfg.moe
-    D, E, F = cfg.d_model, m.n_experts, m.d_expert
+    D, E, F = cfg.d_model, m.held, m.d_expert
     defs = {
-        "router": ParamDef((D, E), (FSDP, None), dtype=jnp.float32),
+        "router": ParamDef((D, m.n_experts), (FSDP, None), dtype=jnp.float32),
         "w_gate": ParamDef((E, D, F), (TP, FSDP, None)),
         "w_up": ParamDef((E, D, F), (TP, FSDP, None)),
         "w_down": ParamDef((E, F, D), (TP, None, FSDP)),
@@ -53,7 +59,8 @@ def moe_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
 
 def _route(xf: jax.Array, router: jax.Array, m: MoEConfig):
     """Top-k routing. Returns (weights (N,k) f32, experts (N,k) i32, aux)."""
-    logits = (xf.astype(jnp.float32) @ router).astype(jnp.float32)
+    logits = jnp.dot(xf.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
     w, idx = jax.lax.top_k(probs, m.top_k)
     if m.normalize_top_k:
@@ -89,6 +96,46 @@ def _pack_by_group(
     pos = jnp.arange(groups.shape[0]) - starts[jnp.minimum(gs, n_groups)]
     keep = (pos < capacity) & (gs < n_groups)
     return order, gs, pos, keep
+
+
+# -- one device: the held experts, dropless ----------------------------------
+
+def moe_apply_held(
+    p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The held experts' weighted outputs for every routed slot, no token
+    dropped, plus the shared experts.  Returns (output, aux, how many
+    routed slots fell on held experts)."""
+    m = cfg.moe
+    B, T, D = x.shape
+    N = B * T
+    xf = x.reshape(N, D)
+    w, idx, aux = _route(xf, p["router"], m)
+    M = N * m.top_k
+    local = idx.reshape(M) - m.first_held
+    held = (local >= 0) & (local < m.held)
+    # slots on experts held elsewhere sort last, outside every group
+    order = jnp.argsort(jnp.where(held, local, m.held), stable=True)
+    sizes = jnp.zeros((m.held,), jnp.int32).at[local].add(
+        held.astype(jnp.int32), mode="drop")
+    tok = order // m.top_k
+    xs = xf[tok]
+
+    def gmm(a, b):
+        return jax.lax.ragged_dot(a, b, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    act_fn = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
+    h = (act_fn(gmm(xs, p["w_gate"])) * gmm(xs, p["w_up"])).astype(x.dtype)
+    y = gmm(h, p["w_down"])
+    # rows outside every group are not defined by ragged_dot: select
+    contrib = jnp.where(held[order][:, None],
+                        y * w.reshape(M)[order][:, None], 0.0)
+    out = jnp.zeros((N, D), jnp.float32).at[tok].add(contrib)
+    out = out.reshape(B, T, D).astype(x.dtype)
+    if m.n_shared:
+        out = out + mlp_apply(p["shared"], x, cfg.act)
+    return out, aux, jnp.sum(held, dtype=jnp.int32)
 
 
 # -- reference path ---------------------------------------------------------
@@ -321,12 +368,19 @@ def moe_apply(
     tp_axis: str = "model",
     zero1: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Dispatching wrapper: picks dense / a2a / gather path."""
+    """Dispatching wrapper: picks the held / dense / a2a / gather path.
+    Returns (output, aux, routed slots on held experts)."""
     if mesh is None or tp_axis not in mesh.axis_names or mesh.shape[tp_axis] == 1:
-        return moe_apply_dense(p, x, cfg)
+        return moe_apply_held(p, x, cfg)
+    if cfg.moe.held != cfg.moe.n_experts:
+        raise ValueError("a layer that holds a share of the experts runs on "
+                         "one device")
     tp = mesh.shape[tp_axis]
     if cfg.moe.n_experts % tp != 0:
-        return moe_apply_dense(p, x, cfg)
-    if x.shape[1] % tp == 0:
-        return moe_apply_a2a(p, x, cfg, mesh, dp_axes, tp_axis, zero1)
-    return moe_apply_gather(p, x, cfg, mesh, dp_axes, tp_axis, zero1)
+        out, aux = moe_apply_dense(p, x, cfg)
+    elif x.shape[1] % tp == 0:
+        out, aux = moe_apply_a2a(p, x, cfg, mesh, dp_axes, tp_axis, zero1)
+    else:
+        out, aux = moe_apply_gather(p, x, cfg, mesh, dp_axes, tp_axis, zero1)
+    routes = jnp.int32(x.shape[0] * x.shape[1] * cfg.moe.top_k)
+    return out, aux, routes

@@ -23,7 +23,8 @@ from repro.models.ctx import ShardCtx
 from repro.models.layers import layer_norm, mlp_apply, mlp_defs, rms_norm, softcap
 from repro.models.param import FSDP, TP, ParamDef, stack_defs
 
-__all__ = ["ShardCtx", "model_defs", "forward", "decode_step", "init_cache"]
+__all__ = ["ShardCtx", "model_defs", "forward", "decode_step", "init_cache",
+           "has_moe"]
 
 
 # -- defs ---------------------------------------------------------------
@@ -134,9 +135,10 @@ def _mixer_apply(p, x, blk: BlockSpec, cfg: ModelConfig, shape: ShapeConfig,
 
 
 def _ffn_apply(p, x, blk: BlockSpec, cfg: ModelConfig, ctx: ShardCtx):
+    """(output, aux loss, routed slots on held experts or None)."""
     if blk.ffn == "dense":
         act = "gelu" if cfg.act == "gelu_plain" else cfg.act
-        return mlp_apply(p, x, act), jnp.zeros((), jnp.float32)
+        return mlp_apply(p, x, act), jnp.zeros((), jnp.float32), None
     if blk.ffn == "moe":
         return moe.moe_apply(p, x, cfg, ctx.mesh, ctx.dp_axes, ctx.tp_axis,
                              zero1=getattr(ctx, 'zero1', False))
@@ -155,7 +157,8 @@ def _block_apply(p, x, blk: BlockSpec, cfg: ModelConfig, shape: ShapeConfig,
     x = x + h
     aux = jnp.zeros((), jnp.float32)
     if blk.ffn != "none":
-        h, aux = _ffn_apply(p["ffn"], _norm_apply(p["norm2"], x, cfg), blk, cfg, ctx)
+        h, aux, _ = _ffn_apply(p["ffn"], _norm_apply(p["norm2"], x, cfg), blk,
+                               cfg, ctx)
         h = jax.ad_checkpoint.checkpoint_name(h, "block_out")
         if cfg.post_block_norm:
             h = _norm_apply(p["post2"], h, cfg)
@@ -312,12 +315,18 @@ def _block_decode(p, x, cache, t, blk: BlockSpec, cfg: ModelConfig,
     if cfg.post_block_norm:
         h = _norm_apply(p["post1"], h, cfg)
     x = x + h
+    routes = None
     if blk.ffn != "none":
-        h, _ = _ffn_apply(p["ffn"], _norm_apply(p["norm2"], x, cfg), blk, cfg, ctx)
+        h, _, routes = _ffn_apply(p["ffn"], _norm_apply(p["norm2"], x, cfg),
+                                  blk, cfg, ctx)
         if cfg.post_block_norm:
             h = _norm_apply(p["post2"], h, cfg)
         x = x + h
-    return x, new_cache
+    return x, new_cache, routes
+
+
+def has_moe(cfg: ModelConfig) -> bool:
+    return any(b.ffn == "moe" for b in cfg.all_blocks())
 
 
 def decode_step(
@@ -327,42 +336,57 @@ def decode_step(
     cache: Dict[str, Any],
     t: jax.Array,  # scalar int32 position of `tokens`
     ctx: Optional[ShardCtx] = None,
-) -> Tuple[jax.Array, Dict[str, Any]]:
-    """One-token decode. Returns (logits (B, V) fp32, new cache)."""
+    held_routes: bool = False,
+):
+    """One-token decode. Returns (logits (B, V) fp32, new cache); with
+    ``held_routes`` (a model with MoE layers) also the number of the
+    token's routed slots, summed over layers, that fell on held experts."""
     ctx = ctx or ShardCtx()
     x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.embed_scale:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    routes = jnp.zeros((), jnp.int32) if held_routes else None
+
+    def add(acc, r):
+        return acc if acc is None or r is None else acc + r
 
     new_prelude = []
     for p, c, blk in zip(params["prelude"], cache["prelude"], cfg.prelude):
-        x, nc = _block_decode(p, x, c, t, blk, cfg, ctx)
+        x, nc, r = _block_decode(p, x, c, t, blk, cfg, ctx)
+        routes = add(routes, r)
         new_prelude.append(nc)
 
     new_body = cache["body"]
     if cfg.n_periods > 0:
-        def period(xx, scanned):
+        def period(carry, scanned):
+            xx, acc = carry
             slot_params, slot_caches = scanned
             new_caches = []
             for sp, sc, blk in zip(slot_params, slot_caches, cfg.pattern):
-                xx, nc = _block_decode(sp, xx, sc, t, blk, cfg, ctx)
+                xx, nc, r = _block_decode(sp, xx, sc, t, blk, cfg, ctx)
+                acc = add(acc, r)
                 new_caches.append(nc)
-            return xx, tuple(new_caches)
+            return (xx, acc), tuple(new_caches)
 
-        x, new_body = jax.lax.scan(
-            period, x, (tuple(params["body"]), tuple(cache["body"]))
+        # without held_routes the count is None: the carry holds x alone
+        (x, routes), new_body = jax.lax.scan(
+            period, (x, routes), (tuple(params["body"]), tuple(cache["body"]))
         )
         new_body = list(new_body)
 
     new_postlude = []
     for p, c, blk in zip(params["postlude"], cache["postlude"], cfg.postlude):
-        x, nc = _block_decode(p, x, c, t, blk, cfg, ctx)
+        x, nc, r = _block_decode(p, x, c, t, blk, cfg, ctx)
+        routes = add(routes, r)
         new_postlude.append(nc)
 
     x = _norm_apply(params["final_norm"], x, cfg)
     logits = logits_fn(params, cfg, x[:, 0])
-    return logits, {
+    new_cache = {
         "prelude": new_prelude,
         "body": new_body,
         "postlude": new_postlude,
     }
+    if held_routes:
+        return logits, new_cache, routes
+    return logits, new_cache

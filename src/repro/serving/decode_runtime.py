@@ -33,22 +33,24 @@ from repro.models import (
     logits_fn,
 )
 from repro.models.attention import AttnCache
+from repro.models.mla import MLACache
 from repro.models.quant_cache import QuantAttnCache
+from repro.models.transformer import has_moe
 from repro.serving.kvpager import KVPager
 
 __all__ = ["PagedDecoder", "flatten_cache", "unflatten_cache"]
 
 
 def _is_layer(x: Any) -> bool:
-    return isinstance(x, (AttnCache, QuantAttnCache))
+    return isinstance(x, (AttnCache, QuantAttnCache, MLACache))
 
 
 def flatten_cache(cache: Any) -> Tuple[List[Any], Any]:
     """Cache pytree → flat list of per-layer caches + treedef.  Attention
-    caches stay whole (one pager layer each — the stacked body caches
-    ride as single leaves with a leading period axis); anything else
-    (ssm/rglru conv state) flattens to opaque array leaves the pager
-    stores whole."""
+    and MLA latent caches stay whole (one pager layer each — the stacked
+    body caches ride as single leaves with a leading period axis);
+    anything else (ssm/rglru conv state) flattens to opaque array leaves
+    the pager stores whole."""
     return jax.tree_util.tree_flatten(cache, is_leaf=_is_layer)
 
 
@@ -85,10 +87,14 @@ class PagedDecoder:
         _, self._treedef = flatten_cache(init_cache(cfg, 1, 2))
         self._prefill = jax.jit(self._prefill_fn)
 
+        # a model with MoE layers also returns how many of the token's
+        # routed slots fell on held experts
+        self._routes = routes = has_moe(cfg)
+
         # named, so that its XLA module is ``jit_paged_decode_step`` and a
         # trace tells it from any other jitted function
         def paged_decode_step(p, tok, cache, t):
-            return decode_step(p, cfg, tok, cache, t)
+            return decode_step(p, cfg, tok, cache, t, held_routes=routes)
 
         self._decode = jax.jit(paged_decode_step)
         self.fn = StatefulFunction(name, self._step, init=self._init,
@@ -126,12 +132,15 @@ class PagedDecoder:
             t = jnp.int32(pos)
             layers, _t_meta = self.pager.load(sid)
             cache = unflatten_cache(self._treedef, layers)
-            logits, new_cache = self._decode(self.params, state["tok"], cache, t)
+            out = self._decode(self.params, state["tok"], cache, t)
+            logits, new_cache = out[0], out[1]
             tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
             # the write-back copies the new cache to the host, which waits
             # for the step anyway: the wait is timed on its own here
             with obs.span("serve.device_wait"):
                 tok.block_until_ready()
+            if self._routes:  # computed by the step that was waited for
+                attrs["held_routes"] = int(out[2])
             new_layers, _ = flatten_cache(new_cache)
             self.pager.write(sid, new_layers, pos)
         return {"session": sid, "t": t, "tok": tok}, tok

@@ -23,7 +23,8 @@ block), so the hierarchy can place each session independently:
 
 The pager is deliberately ignorant of transformer structure: it pages a
 flat list of per-layer caches (:class:`AttnCache` /
-:class:`QuantAttnCache` / opaque array leaves for recurrent mixers);
+:class:`QuantAttnCache` / :class:`MLACache` latents / opaque array leaves
+for recurrent mixers);
 ``decode_runtime`` owns the flatten/unflatten against the model's cache
 pytree.
 """
@@ -39,20 +40,22 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.models.attention import AttnCache
+from repro.models.mla import MLACache
 from repro.models.quant_cache import QuantAttnCache, quantize_kv
 from repro.storage import serde
 
 __all__ = ["KVPager", "PagerStats"]
 
 #: per-layer kinds recorded in the session meta record
-_ATTN, _QUANT, _OPAQUE = "attn", "quant", "opaque"
+_ATTN, _QUANT, _MLA, _OPAQUE = "attn", "quant", "mla", "opaque"
 
 
 class PagerStats:
     """Cumulative pager counters (the fig10 observables)."""
 
     __slots__ = ("demotions", "resumes", "demand_faults", "quantized_blocks",
-                 "blocks_written", "max_resident")
+                 "blocks_written", "max_resident", "latent_blocks",
+                 "latent_bytes")
 
     def __init__(self) -> None:
         self.demotions = 0
@@ -61,6 +64,9 @@ class PagerStats:
         self.quantized_blocks = 0
         self.blocks_written = 0
         self.max_resident = 0
+        #: blocks and serialized bytes of MLA latent caches written
+        self.latent_blocks = 0
+        self.latent_bytes = 0
 
     def as_dict(self) -> Dict[str, int]:
         return {name: getattr(self, name) for name in self.__slots__}
@@ -93,10 +99,14 @@ def _layer_kind(layer: Any) -> str:
         return _QUANT
     if isinstance(layer, AttnCache):
         return _ATTN
+    if isinstance(layer, MLACache):
+        return _MLA
     return _OPAQUE
 
 
 def _seq_len(layer: Any) -> int:
+    if isinstance(layer, MLACache):  # (..., S, r): sequence axis -2
+        return int(layer.c_kv.shape[-2])
     arr = layer.k_q if isinstance(layer, QuantAttnCache) else layer.k
     return int(arr.shape[-3])
 
@@ -110,7 +120,7 @@ def _quantize_layer(layer: AttnCache) -> QuantAttnCache:
 def _slice_block(layer: Any, lo: int, hi: int) -> Dict[str, Any]:
     """One (layer, block) blob: the block's token slots from every array
     of the layer cache.  Values/int8 carry the sequence axis at -3,
-    quant scales at -2; opaque leaves are stored whole."""
+    quant scales and MLA latents at -2; opaque leaves are stored whole."""
     if isinstance(layer, QuantAttnCache):
         return {
             "k_q": layer.k_q[..., lo:hi, :, :],
@@ -120,6 +130,9 @@ def _slice_block(layer: Any, lo: int, hi: int) -> Dict[str, Any]:
         }
     if isinstance(layer, AttnCache):
         return {"k": layer.k[..., lo:hi, :, :], "v": layer.v[..., lo:hi, :, :]}
+    if isinstance(layer, MLACache):
+        return {"c_kv": layer.c_kv[..., lo:hi, :],
+                "k_pe": layer.k_pe[..., lo:hi, :]}
     return {"x": layer}
 
 
@@ -135,6 +148,8 @@ def _join_blocks(kind: str, parts: List[Dict[str, Any]]) -> Any:
             k_q=cat("k_q", -3), v_q=cat("v_q", -3),
             k_s=cat("k_s", -2), v_s=cat("v_s", -2),
         )
+    if kind == _MLA:
+        return MLACache(c_kv=cat("c_kv", -2), k_pe=cat("k_pe", -2))
     return AttnCache(k=cat("k", -3), v=cat("v", -3))
 
 
@@ -265,7 +280,7 @@ class KVPager:
             pin = getattr(self.store, "pin", None)
             if pin is not None:
                 pin(self.session_prefix(sid))
-            attrs["blocks"], attrs["bytes"] = self._write_blocks(ent, dirty=None)
+            attrs.update(self._write_blocks(ent, dirty=None))
         self._note_resident_peak()
 
     def write(self, sid: str, layers: Sequence[Any], t: int) -> None:
@@ -285,17 +300,18 @@ class KVPager:
                 else:
                     slot = int(t) % _seq_len(layer)
                     dirty.add((li, slot // self.block_tokens))
-            attrs["blocks"], attrs["bytes"] = self._write_blocks(
-                ent, dirty=dirty)
+            attrs.update(self._write_blocks(ent, dirty=dirty))
 
     def _write_blocks(
         self, ent: _Session, dirty: Optional[set] = None
-    ) -> Tuple[int, int]:
+    ) -> Dict[str, Any]:
         """Serialize + put the selected (layer, block) blobs and the meta
         record in one batched ``put_many``.  Caller holds ``ent.lock``.
-        Returns the blocks and bytes written."""
+        Returns the blocks and bytes written and their layer kinds
+        (``+``-joined)."""
         assert ent.resident is not None
         items: Dict[str, bytes] = {}
+        kinds = set()
         meta_layers = []
         # slicing, the copy to the host and the encoding
         with obs.span("pager.serialize") as attrs:
@@ -314,10 +330,16 @@ class KVPager:
                     hi = min(S, lo + self.block_tokens) if kind != _OPAQUE else 0
                     blob = serde.dumps(_slice_block(layer, lo, hi))
                     items[self._block_key(ent.sid, li, b)] = blob
+                    kinds.add(kind)
                     if kind == _QUANT:
                         self.stats.quantized_blocks += 1
+                    elif kind == _MLA:
+                        self.stats.latent_blocks += 1
+                        self.stats.latent_bytes += len(blob)
             attrs["blocks"] = len(items)
             attrs["bytes"] = sum(len(b) for b in items.values())
+            attrs["kind"] = "+".join(sorted(kinds))
+            written = dict(attrs)
         meta = {
             "t": ent.t,
             "quantized": ent.quantized,
@@ -329,7 +351,7 @@ class KVPager:
         for key, blob in items.items():
             ent.sizes[key] = len(blob)
         self.stats.blocks_written += len(items) - 1
-        return attrs["blocks"], attrs["bytes"]
+        return written
 
     # -- read path ----------------------------------------------------------
     def load(self, sid: str) -> Tuple[List[Any], int]:
@@ -385,6 +407,8 @@ class KVPager:
                 if ent.resident is None:
                     self._assemble(ent)
                 assert ent.resident is not None
+                # only k/v rings are quantized: MLA latents (and opaque
+                # leaves) are demoted raw in both modes
                 ent.resident = [
                     _quantize_layer(l) if isinstance(l, AttnCache) else l
                     for l in ent.resident

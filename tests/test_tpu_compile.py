@@ -100,3 +100,33 @@ def test_qwen_decode_step_fits_one_v5e(one_chip):
     ).lower(params, tok, cache, t).compile()
     args = compiled.memory_analysis().argument_size_in_bytes
     assert 6 << 30 < args < V5E_HBM_BYTES
+
+
+def test_deepseek_expert_share_fits_one_v5e(one_chip):
+    # DeepSeek-V2-Lite as one chip's EP=4 share (16 of 64 routed experts a
+    # layer) at published widths: the paged decode step, with its held
+    # routes, and a prefill whose dropless expert layer is a ragged dot
+    from dataclasses import replace
+
+    from repro.models import ShapeConfig, forward
+
+    full = get_config("deepseek-v2-lite-16b")
+    cfg = replace(full, moe=replace(full.moe, n_held=16))
+    on_chip = lambda s: _shape(s.shape, s.dtype, one_chip)  # noqa: E731
+    params = jax.tree.map(on_chip, abstract_params(model_defs(cfg)))
+    cache = jax.tree.map(on_chip, jax.eval_shape(lambda: init_cache(cfg, 1, 160)))
+    tok = _shape((1, 1), jnp.int32, one_chip)
+    t = _shape((), jnp.int32, one_chip)
+    compiled = jax.jit(
+        lambda p, tok, c, t: decode_step(p, cfg, tok, c, t, held_routes=True)
+    ).lower(params, tok, cache, t).compile()
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert 9 << 30 < args < V5E_HBM_BYTES
+    shape = ShapeConfig(name="serve", kind="prefill", seq_len=256,
+                        global_batch=1, q_chunk=8, kv_chunk=8, remat="none")
+    prompt = _shape((1, 256), jnp.int32, one_chip)
+    compiled = jax.jit(
+        lambda p, x: forward(p, cfg, {"tokens": x}, shape, collect_cache=True,
+                             cache_len=288)
+    ).lower(params, prompt).compile()
+    assert "ragged-dot" in compiled.as_text()
