@@ -19,7 +19,8 @@ from __future__ import annotations
 
 from typing import Dict, Tuple
 
-_DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
+from chipbench.counts import _DTYPE_BYTES
+
 _ROUTER_BYTES = 4  # the router is float32, as published
 
 
@@ -134,9 +135,3 @@ def weight_bytes(cfg: Dict) -> int:
     held = moe * _i(cfg, "n_routed_experts") * expert_params(cfg)
     return ((bf16 + held + d * v) * _DTYPE_BYTES[cfg["torch_dtype"]]
             + router * _ROUTER_BYTES)
-
-
-def roofline_seconds(flops: float, nbytes: float, peak: Dict) -> float:
-    """The least time the chip could take: compute or memory bound."""
-    return max(flops / peak["bf16_flops_per_s"],
-               nbytes / peak["hbm_bytes_per_s"])

@@ -6,6 +6,7 @@ averaged over the window's decode steps) over their device time."""
 
 from chipbench import counts_mla_moe as counts
 from chipbench import decode_attrs
+from chipbench.counts import roofline_seconds
 
 
 def read(out, ctx):
@@ -16,7 +17,7 @@ def read(out, ctx):
     if not runs:
         return None
     cfg = ctx.cell.config
-    least = sum(counts.roofline_seconds(*counts.decode_cost(
+    least = sum(roofline_seconds(*counts.decode_cost(
         cfg, s["position"] + 1, s["held_routes"]), ctx.peak)
         for s in steps) / len(steps)
     return 100.0 * runs * least / device_s

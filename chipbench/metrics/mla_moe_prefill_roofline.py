@@ -5,6 +5,7 @@ time of all of them (``counts_mla_moe``) over the time of their
 device."""
 
 from chipbench import counts_mla_moe as counts
+from chipbench.counts import roofline_seconds
 
 
 def read(out, ctx):
@@ -13,6 +14,6 @@ def read(out, ctx):
     if ctx.peak is None or not spans or len(spans) != len(lengths):
         return None
     cfg = ctx.cell.config
-    least = sum(counts.roofline_seconds(*counts.prefill_cost(cfg, int(t)), ctx.peak)
+    least = sum(roofline_seconds(*counts.prefill_cost(cfg, int(t)), ctx.peak)
                 for t in lengths)
     return 100.0 * least / sum(b - a for a, b in spans)
