@@ -95,7 +95,7 @@ def reference_decode(params, cfg, prompt, max_tokens: int):
     tok, cache = prefill(params, prompt)
     out = []
     for i in range(max_tokens):
-        logits, cache = step(params, tok, cache, jnp.int32(plen + i))
+        logits, cache, _ = step(params, tok, cache, jnp.int32(plen + i))
         tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
         out.append(int(tok[0, 0]))
     return out

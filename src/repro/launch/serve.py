@@ -82,7 +82,7 @@ def main():
     t0 = time.perf_counter()
     for i in range(args.tokens - 1):
         pos = jnp.int32(args.prompt_len + i)
-        logits, caches = step(params, tok, caches, pos)
+        logits, caches, _ = step(params, tok, caches, pos)
         key, sub = jax.random.split(key)
         tok = sample(logits, sub)[:, None]
         out_tokens.append(tok)

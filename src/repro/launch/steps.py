@@ -278,7 +278,7 @@ def make_decode_step(
     B, S = shape.global_batch, shape.seq_len
 
     def serve_step(params, tokens, cache, t):
-        logits, new_cache = decode_step(params, cfg, tokens, cache, t, ctx)
+        logits, new_cache, _ = decode_step(params, cfg, tokens, cache, t, ctx)
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
         return next_tok, new_cache
 

@@ -367,9 +367,10 @@ def moe_apply(
     dp_axes: Tuple[str, ...] = ("data",),
     tp_axis: str = "model",
     zero1: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """Dispatching wrapper: picks the held / dense / a2a / gather path.
-    Returns (output, aux, routed slots on held experts)."""
+    Returns (output, aux, routed slots on held experts); the sharded paths
+    hold every expert and count none (None)."""
     if mesh is None or tp_axis not in mesh.axis_names or mesh.shape[tp_axis] == 1:
         return moe_apply_held(p, x, cfg)
     if cfg.moe.held != cfg.moe.n_experts:
@@ -382,5 +383,4 @@ def moe_apply(
         out, aux = moe_apply_a2a(p, x, cfg, mesh, dp_axes, tp_axis, zero1)
     else:
         out, aux = moe_apply_gather(p, x, cfg, mesh, dp_axes, tp_axis, zero1)
-    routes = jnp.int32(x.shape[0] * x.shape[1] * cfg.moe.top_k)
-    return out, aux, routes
+    return out, aux, None

@@ -23,8 +23,7 @@ from repro.models.ctx import ShardCtx
 from repro.models.layers import layer_norm, mlp_apply, mlp_defs, rms_norm, softcap
 from repro.models.param import FSDP, TP, ParamDef, stack_defs
 
-__all__ = ["ShardCtx", "model_defs", "forward", "decode_step", "init_cache",
-           "has_moe"]
+__all__ = ["ShardCtx", "model_defs", "forward", "decode_step", "init_cache"]
 
 
 # -- defs ---------------------------------------------------------------
@@ -325,10 +324,6 @@ def _block_decode(p, x, cache, t, blk: BlockSpec, cfg: ModelConfig,
     return x, new_cache, routes
 
 
-def has_moe(cfg: ModelConfig) -> bool:
-    return any(b.ffn == "moe" for b in cfg.all_blocks())
-
-
 def decode_step(
     params: Dict[str, Any],
     cfg: ModelConfig,
@@ -336,19 +331,20 @@ def decode_step(
     cache: Dict[str, Any],
     t: jax.Array,  # scalar int32 position of `tokens`
     ctx: Optional[ShardCtx] = None,
-    held_routes: bool = False,
 ):
-    """One-token decode. Returns (logits (B, V) fp32, new cache); with
-    ``held_routes`` (a model with MoE layers) also the number of the
-    token's routed slots, summed over layers, that fell on held experts."""
+    """One-token decode. Returns (logits (B, V) fp32, new cache, held
+    routes): the number of the token's routed slots, summed over layers,
+    that fell on held experts, or None where no layer routes to a share of
+    its experts on one device (a dense model, or the sharded expert
+    paths)."""
     ctx = ctx or ShardCtx()
     x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.embed_scale:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
-    routes = jnp.zeros((), jnp.int32) if held_routes else None
+    routes = None
 
     def add(acc, r):
-        return acc if acc is None or r is None else acc + r
+        return r if acc is None else acc if r is None else acc + r
 
     new_prelude = []
     for p, c, blk in zip(params["prelude"], cache["prelude"], cfg.prelude):
@@ -358,21 +354,23 @@ def decode_step(
 
     new_body = cache["body"]
     if cfg.n_periods > 0:
-        def period(carry, scanned):
-            xx, acc = carry
+        def period(xx, scanned):
             slot_params, slot_caches = scanned
-            new_caches = []
+            new_caches, acc = [], None
             for sp, sc, blk in zip(slot_params, slot_caches, cfg.pattern):
                 xx, nc, r = _block_decode(sp, xx, sc, t, blk, cfg, ctx)
                 acc = add(acc, r)
                 new_caches.append(nc)
-            return (xx, acc), tuple(new_caches)
+            return xx, (tuple(new_caches), acc)
 
-        # without held_routes the count is None: the carry holds x alone
-        (x, routes), new_body = jax.lax.scan(
-            period, (x, routes), (tuple(params["body"]), tuple(cache["body"]))
+        # each period's count is a scanned output (None adds none), so the
+        # carry holds x alone
+        x, (new_body, body_routes) = jax.lax.scan(
+            period, x, (tuple(params["body"]), tuple(cache["body"]))
         )
         new_body = list(new_body)
+        if body_routes is not None:
+            routes = add(routes, jnp.sum(body_routes))
 
     new_postlude = []
     for p, c, blk in zip(params["postlude"], cache["postlude"], cfg.postlude):
@@ -382,11 +380,8 @@ def decode_step(
 
     x = _norm_apply(params["final_norm"], x, cfg)
     logits = logits_fn(params, cfg, x[:, 0])
-    new_cache = {
+    return logits, {
         "prelude": new_prelude,
         "body": new_body,
         "postlude": new_postlude,
-    }
-    if held_routes:
-        return logits, new_cache, routes
-    return logits, new_cache
+    }, routes

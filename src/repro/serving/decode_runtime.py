@@ -35,7 +35,6 @@ from repro.models import (
 from repro.models.attention import AttnCache
 from repro.models.mla import MLACache
 from repro.models.quant_cache import QuantAttnCache
-from repro.models.transformer import has_moe
 from repro.serving.kvpager import KVPager
 
 __all__ = ["PagedDecoder", "flatten_cache", "unflatten_cache"]
@@ -87,14 +86,10 @@ class PagedDecoder:
         _, self._treedef = flatten_cache(init_cache(cfg, 1, 2))
         self._prefill = jax.jit(self._prefill_fn)
 
-        # a model with MoE layers also returns how many of the token's
-        # routed slots fell on held experts
-        self._routes = routes = has_moe(cfg)
-
         # named, so that its XLA module is ``jit_paged_decode_step`` and a
         # trace tells it from any other jitted function
         def paged_decode_step(p, tok, cache, t):
-            return decode_step(p, cfg, tok, cache, t, held_routes=routes)
+            return decode_step(p, cfg, tok, cache, t)
 
         self._decode = jax.jit(paged_decode_step)
         self.fn = StatefulFunction(name, self._step, init=self._init,
@@ -132,15 +127,15 @@ class PagedDecoder:
             t = jnp.int32(pos)
             layers, _t_meta = self.pager.load(sid)
             cache = unflatten_cache(self._treedef, layers)
-            out = self._decode(self.params, state["tok"], cache, t)
-            logits, new_cache = out[0], out[1]
+            logits, new_cache, routes = self._decode(
+                self.params, state["tok"], cache, t)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
             # the write-back copies the new cache to the host, which waits
             # for the step anyway: the wait is timed on its own here
             with obs.span("serve.device_wait"):
                 tok.block_until_ready()
-            if self._routes:  # computed by the step that was waited for
-                attrs["held_routes"] = int(out[2])
+            if routes is not None:  # computed by the step waited for
+                attrs["held_routes"] = int(routes)
             new_layers, _ = flatten_cache(new_cache)
             self.pager.write(sid, new_layers, pos)
         return {"session": sid, "t": t, "tok": tok}, tok

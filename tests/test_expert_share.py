@@ -169,3 +169,25 @@ def test_a_share_of_the_experts_runs_on_a_one_device_expert_axis():
     # one device on the expert axis: the held path, no exchange
     out, _, _ = moe_apply(_params(cfg), jnp.ones((1, 2, cfg.d_model)), cfg, mesh)
     assert out.shape == (1, 2, cfg.d_model)
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "deepseek-v2-lite-16b"])
+def test_decode_step_counts_held_routes_only_for_a_held_share(arch):
+    """A dense model's decode step counts no routes (None); a layer that
+    holds every expert counts all top-k slots of every MoE layer, and a
+    share of them no more than that."""
+    from repro.models import decode_step, init_cache, model_defs
+
+    def routes(cfg):
+        params = init_params(model_defs(cfg), jax.random.PRNGKey(6))
+        return decode_step(params, cfg, jnp.ones((1, 1), jnp.int32),
+                           init_cache(cfg, 1, 8), jnp.int32(0))[2]
+
+    cfg = reduced_for_smoke(get_config(arch))
+    if cfg.moe is None:
+        assert routes(cfg) is None
+        return
+    every = sum(b.ffn == "moe" for b in cfg.all_blocks()) * cfg.moe.top_k
+    assert int(routes(cfg)) == every > 0
+    half = replace(cfg, moe=replace(cfg.moe, n_held=cfg.moe.n_experts // 2))
+    assert 0 <= int(routes(half)) <= every

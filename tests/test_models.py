@@ -207,7 +207,7 @@ def test_arch_decode_matches_forward(arch):
     step = jax.jit(lambda p, tok, c, t: decode_step(p, cfg, tok, c, t))
     errs = []
     for t in range(T):
-        lg, cache = step(params, toks[:, t : t + 1], cache, jnp.int32(t))
+        lg, cache, _ = step(params, toks[:, t : t + 1], cache, jnp.int32(t))
         errs.append(np.abs(np.asarray(lg) - full_logits[:, t]).max())
     assert max(errs) < 2e-2, f"{arch}: max decode divergence {max(errs)}"
 
@@ -228,7 +228,7 @@ def test_prefill_cache_matches_decode_path():
     step = jax.jit(lambda p, tok, c, t: decode_step(p, cfg, tok, c, t))
     logits_a = None
     for t in range(total):
-        logits_a, cache = step(params, toks[:, t : t + 1], cache,
+        logits_a, cache, _ = step(params, toks[:, t : t + 1], cache,
                                jnp.int32(t))
     # path B: prefill Tp, decode the rest
     h, _aux, cache_b = forward(
@@ -238,7 +238,7 @@ def test_prefill_cache_matches_decode_path():
     cache_b = _f32(cache_b)
     logits_b = logits_fn(params, cfg, h[:, -1])
     for i in range(Tg):
-        logits_b, cache_b = step(
+        logits_b, cache_b, _ = step(
             params, toks[:, Tp + i : Tp + i + 1], cache_b,
             jnp.int32(Tp + i),
         )
@@ -298,9 +298,9 @@ def test_int8_kv_cache_decode_close_to_bf16():
     )
     errs, agree = [], 0
     for t in range(T):
-        l1, c_ref = decode_step(params, cfg, toks[:, t : t + 1], c_ref,
+        l1, c_ref, _ = decode_step(params, cfg, toks[:, t : t + 1], c_ref,
                                 jnp.int32(t))
-        l2, c_q = decode_step(params, cfg, toks[:, t : t + 1], c_q,
+        l2, c_q, _ = decode_step(params, cfg, toks[:, t : t + 1], c_q,
                               jnp.int32(t))
         errs.append(np.abs(np.asarray(l1) - np.asarray(l2)).max())
         agree += int(

@@ -85,7 +85,7 @@ class _Oracle:
         t, tok = self.state[sid]
         cache = unflatten_cache(self.decoder._treedef, self.cache[sid])
         t = t + 1
-        logits, new_cache = self.decoder._decode(
+        logits, new_cache, _ = self.decoder._decode(
             self.decoder.params, tok, cache, jnp.int32(t))
         new_tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
         self.cache[sid], _ = flatten_cache(new_cache)

@@ -118,7 +118,7 @@ def test_deepseek_expert_share_fits_one_v5e(one_chip):
     tok = _shape((1, 1), jnp.int32, one_chip)
     t = _shape((), jnp.int32, one_chip)
     compiled = jax.jit(
-        lambda p, tok, c, t: decode_step(p, cfg, tok, c, t, held_routes=True)
+        lambda p, tok, c, t: decode_step(p, cfg, tok, c, t)
     ).lower(params, tok, cache, t).compile()
     args = compiled.memory_analysis().argument_size_in_bytes
     assert 9 << 30 < args < V5E_HBM_BYTES
