@@ -51,6 +51,7 @@ from dataclasses import dataclass
 from typing import (
     TYPE_CHECKING,
     Callable,
+    Collection,
     Dict,
     Iterable,
     Iterator,
@@ -141,9 +142,19 @@ class TieredStore(Tier):
     acknowledged-unflushed put dies with the volatile fast level.
 
     Thread-safe: placement metadata is under one store lock, held across
-    inline tier ops (they are fast levels by construction); the flusher's
-    home ``put_many`` runs outside it so a slow home device never blocks
-    the hot path.
+    the inline fast-level ops of a put, get, demotion or promotion (fast
+    levels by construction) and across the flusher's snapshot and
+    settle.  Two kinds of slow work run with it released: the flusher's
+    home ``put_many``, so a slow home device never blocks the hot path,
+    and a write-back put's redo record, whose journal writes carry the
+    fsyncs.  A put marks its keys' redo pending under the lock and
+    returns only once the record is durable.  Until then a put, install,
+    delete or get of such a key waits for it (``redo_order_waits``), the
+    flusher skips it and no demotion lands it at home, so a key's redo
+    records land in version order and no retract races one.  A redo
+    write that fails raises to the put's caller, unacknowledged, but
+    leaves the put installed and dirty: the flusher lands that value at
+    home, so at most the one failed put takes effect.
     """
 
     def __init__(
@@ -176,6 +187,9 @@ class TieredStore(Tier):
         #: keys with a redo record in the journal (volatile: ``recover``
         #: rebuilds it from the journal).
         self._redo: set = set()
+        #: keys whose redo record a put is writing with the lock released;
+        #: ``_redo_landed`` is signalled as each batch lands.
+        self._redo_pending: set = set()
         #: pinned key prefixes: matching keys are held in the fast level —
         #: never demotion victims, promoted on first read (see :meth:`pin`).
         self._pins: set = set()
@@ -185,6 +199,7 @@ class TieredStore(Tier):
         #: clobber it after the dirty record was cleared.
         self._inflight_flush: set = set()
         self._mutex = threading.RLock()
+        self._redo_landed = threading.Condition(self._mutex)
         #: flusher wake-up signal, deliberately NOT built on ``_mutex``:
         #: cross-store prefetch callbacks run on the writer's thread and
         #: must never need another store's placement lock.
@@ -203,6 +218,8 @@ class TieredStore(Tier):
         self.demotions = 0
         #: keys put through :meth:`install_many`, with no redo record.
         self.unjournaled_puts = 0
+        #: operations that waited for a same-key redo still in flight.
+        self.redo_order_waits = 0
         self.flush_errors = 0
         self._hits: List[int] = [0 for _ in self.levels]
         self._closed = False
@@ -300,12 +317,16 @@ class TieredStore(Tier):
             # requests (warm-pool spills) are refused too.
             return False
         src, dst = ent.level, ent.level + 1
-        if dst == self._home and key in self._inflight_flush:
+        if dst == self._home and (
+            key in self._inflight_flush or key in self._redo_pending
+        ):
             # A flush round snapshotted this key and its home put_many
             # has not landed yet: writing home here and clearing the
             # dirty record would let the in-flight (older) batch clobber
-            # the newer value afterwards.  Leave the key where it is;
-            # the flusher settles it within a round.
+            # the newer value afterwards.  A pending redo is the same
+            # race with the journal: the retract would precede the
+            # record it retracts.  Leave the key where it is; the
+            # flusher settles it within a round.
             return False
         src_tier = self.levels[src].tier
         if dst == self._home and ent.home_copy and key not in self._dirty:
@@ -381,20 +402,33 @@ class TieredStore(Tier):
             with obs.span("tier.lock_wait", store=self.name):
                 self._mutex.acquire()
 
+    def _await_redo(self, keys: Collection[str]) -> None:
+        """With the placement lock held, wait (the lock released
+        meanwhile) until no key of ``keys`` has a redo record in flight;
+        the wait is the span ``tier.lock_wait`` with ``lock="redo"``."""
+        if self._redo_pending.isdisjoint(keys):
+            return
+        self.redo_order_waits += 1
+        with obs.span("tier.lock_wait", store=self.name, lock="redo"):
+            while not self._redo_pending.isdisjoint(keys):
+                self._redo_landed.wait()
+
     def put(self, key: str, value: bytes) -> None:
         t0 = time.perf_counter()
         with obs.span("tier.put", keys=1, bytes=len(value)), \
                 tier_accounting_capture() as inline:
             self._acquire()
             try:
+                self._await_redo((key,))
                 self._install(key, value)
-                self._journal_put({key: value})
+                redo = self._stage_redo({key: value})
                 if not self.policy.write_back:
                     self._write_home(key, value)
                 else:
                     self._dirty[key] = self._entries[key].version
             finally:
                 self._mutex.release()
+            self._journal_put(redo)
         if self.policy.write_back:
             self._wake.set()
         self._logical_write(len(value), time.perf_counter() - t0,
@@ -422,11 +456,13 @@ class TieredStore(Tier):
                 tier_accounting_capture() as inline:
             self._acquire()
             try:
+                self._await_redo(items)
                 for key, value in items.items():
                     self._install(key, value)
                 if redo:
-                    self._journal_put(items)
+                    staged = self._stage_redo(items)
                 else:
+                    staged = {}
                     for key in items:
                         self._retract(key)
                     self.unjournaled_puts += len(items)
@@ -442,6 +478,7 @@ class TieredStore(Tier):
                         self._dirty[key] = self._entries[key].version
             finally:
                 self._mutex.release()
+            self._journal_put(staged)
         if self.policy.write_back:
             self._wake.set()
         self._logical_write(total, time.perf_counter() - t0,
@@ -475,24 +512,49 @@ class TieredStore(Tier):
         self.levels[self._home].tier.put(key, value)
         self._entries[key].home_copy = True
 
-    def _journal_put(self, items: Mapping[str, bytes]) -> None:
+    def _stage_redo(
+        self, items: Mapping[str, bytes]
+    ) -> Dict[str, Tuple[bytes, int]]:
+        """Under the placement lock: mark the redo records of ``items``,
+        just installed, pending, and return each value with the version
+        it was installed at, for :meth:`_journal_put`.  Empty when the
+        store keeps no redo log."""
         if self._journal is None or not self.policy.write_back:
-            return
+            return {}
         self._redo.update(items)
-        # Redo blobs first, then their markers: a torn journal batch can
-        # leave orphan blobs (garbage, harmless) but never a marker whose
-        # blob is missing — recovery skips markers without blobs anyway.
-        with obs.span("tier.journal_put", keys=len(items),
-                      bytes=sum(len(v) for v in items.values())) as attrs, \
-                tier_accounting_capture() as io:
-            self._journal_cache.put_many(
-                {self._data_key(k): v for k, v in items.items()}
-            )
-            self._journal.commit_many(
-                {k: {"bytes": len(v), "seq": self._entries[k].version}
-                 for k, v in items.items()}
-            )
-            attrs["syncs"] = io.syncs
+        self._redo_pending.update(items)
+        return {k: (v, self._entries[k].version) for k, v in items.items()}
+
+    def _journal_put(self, staged: Mapping[str, Tuple[bytes, int]]) -> None:
+        """Write the redo records of :meth:`_stage_redo` with the
+        placement lock released, then clear them from pending, whether
+        or not the write succeeded (see the class docstring)."""
+        if not staged:
+            return
+        try:
+            # Redo blobs first, then their markers: a torn journal batch
+            # can leave orphan blobs (garbage, harmless) but never a
+            # marker whose blob is missing — recovery skips markers
+            # without blobs anyway.
+            with obs.span("tier.journal_put", keys=len(staged),
+                          bytes=sum(len(v) for v, _ in staged.values())
+                          ) as attrs, \
+                    tier_accounting_capture() as io:
+                self._journal_cache.put_many(
+                    {self._data_key(k): v for k, (v, _) in staged.items()}
+                )
+                self._journal.commit_many(
+                    {k: {"bytes": len(v), "seq": seq}
+                     for k, (v, seq) in staged.items()}
+                )
+                attrs["syncs"] = io.syncs
+        finally:
+            self._acquire()
+            try:
+                self._redo_pending.difference_update(staged)
+                self._redo_landed.notify_all()
+            finally:
+                self._mutex.release()
 
     def get(self, key: str) -> bytes:
         t0 = time.perf_counter()
@@ -500,6 +562,8 @@ class TieredStore(Tier):
                 tier_accounting_capture() as inline:
             self._acquire()
             try:
+                # A value is served only once its redo record is durable.
+                self._await_redo((key,))
                 ent = self._entries.get(key)
                 if ent is None:
                     ent = self._adopt(key)
@@ -532,6 +596,7 @@ class TieredStore(Tier):
 
     def delete(self, key: str) -> None:
         with self._mutex:
+            self._await_redo((key,))
             ent = self._entries.pop(key, None)
             if ent is not None:
                 self._drop_from_level(key, ent.level, ent.size)
@@ -624,7 +689,13 @@ class TieredStore(Tier):
     def _snapshot_batch(self) -> List[Tuple[str, int, bytes]]:
         with self._mutex:
             batch: List[Tuple[str, int, bytes]] = []
-            for key in list(self._dirty)[: self.policy.flush_batch]:
+            for key in list(self._dirty):
+                if len(batch) >= self.policy.flush_batch:
+                    break
+                if key in self._redo_pending:
+                    # Its flush would retract a redo record that has not
+                    # landed yet; the key stays dirty until a later round.
+                    continue
                 ent = self._entries.get(key)
                 if ent is None:  # deleted since marked dirty
                     self._dirty.pop(key, None)
@@ -700,12 +771,20 @@ class TieredStore(Tier):
                 if not self._dirty:
                     return flushed
             try:
-                flushed += self._flush_once()
+                n = self._flush_once()
             except Exception:
                 self.flush_errors += 1
                 if deadline is not None and time.monotonic() >= deadline:
                     raise
                 time.sleep(min(0.005, self.policy.flush_interval))
+                continue
+            flushed += n
+            if not n:
+                # Every dirty key's redo is still in flight: wait for one
+                # to land instead of spinning on empty rounds.
+                with self._redo_landed:
+                    if self._redo_pending:
+                        self._redo_landed.wait(self.policy.flush_interval)
 
     @property
     def dirty_keys(self) -> List[str]:

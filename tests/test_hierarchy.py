@@ -6,6 +6,7 @@ state path, hierarchy-backed BlockStore DataNodes, and the adaptive
 (write-back) MapReduce shuffle.
 """
 
+import threading
 import time
 
 import pytest
@@ -19,10 +20,12 @@ from repro.storage import (
     DeviceSpec,
     DramTier,
     FaultInjectingTier,
+    InjectedIOError,
     PlacementPolicy,
     PmemTier,
     SimulatedTier,
     StateCache,
+    Tier,
     TieredStore,
     TierLevel,
 )
@@ -224,6 +227,216 @@ def test_demote_skips_keys_pinned_by_inflight_flush():
     assert store.demote("k")
     assert store.level_of("k") == "home"
     assert store.get("k") == b"v"
+    store.close()
+
+
+# -- write-back redo records, written with the placement lock released --------
+
+class _GatedJournalDevice(Tier):
+    """A durable journal device whose writes, once ``hold()`` is called,
+    wait at a gate until the test opens it: a slow fsync kept open for as
+    long as the test needs.  With ``fail`` set a held write raises when
+    released, as a device lost before the record landed.  ``writes``
+    lists what landed, in order."""
+
+    name = "gated"
+    persistent = True
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.backing = DramTier()  # survives the journal cache's crash()
+        self.gate = threading.Event()
+        self.gate.set()
+        self.entered = threading.Event()
+        self.fail = False
+        self.writes = []
+
+    def hold(self) -> None:
+        self.entered.clear()
+        self.gate.clear()
+
+    def put(self, key, value):
+        self.put_many({key: value})
+
+    def put_many(self, items):
+        if not self.gate.is_set():
+            self.entered.set()
+            self.gate.wait(10.0)
+            if self.fail:
+                raise InjectedIOError("journal device lost mid-write")
+        self.backing.put_many(items)
+        self.writes.extend(items.items())
+
+    def get(self, key):
+        return self.backing.get(key)
+
+    def delete(self, key):
+        self.backing.delete(key)
+
+    def contains(self, key):
+        return self.backing.contains(key)
+
+    def keys(self, prefix=""):
+        return self.backing.keys(prefix)
+
+    def size_of(self, key):
+        return self.backing.size_of(key)
+
+
+def _gated_store(home=None, flush_interval=0.005):
+    """A write-back store whose redo log rides a gated journal device."""
+    device = _GatedJournalDevice()
+    journal = StateCache(write_through=device)
+    store, home = _stack(
+        policy=PlacementPolicy(write_back=True, flush_interval=flush_interval),
+        journal=journal, home=home if home is not None else DramTier(),
+        name="redo")
+    return store, home, journal, device
+
+
+def _start(fn, *args):
+    out = []
+
+    def run():
+        try:
+            out.append(fn(*args))
+        except Exception as exc:  # the test inspects it
+            out.append(exc)
+
+    th = threading.Thread(target=run)
+    th.start()
+    return th, out
+
+
+def _torn_home(tmp_path):
+    """A home level whose flushes always tear: every acked put stays
+    dirty, so recovery has only the journal to go on."""
+    return FaultInjectingTier(PmemTier(str(tmp_path / "home")), seed=0,
+                              torn_put_many_rate=1.0)
+
+
+def test_installs_and_gets_proceed_while_a_redo_is_in_flight():
+    store, _, _, device = _gated_store()
+    store.put("q", b"read me")
+    device.hold()
+    writer, _ = _start(store.put_many, {"p": b"x" * 64, "p2": b"y" * 64})
+    assert device.entered.wait(10.0)
+    # the committer's install and a reader finish behind the open redo
+    store.install_many({"s": b"state"})
+    assert store.get("q") == b"read me"
+    assert store.get("s") == b"state"
+    assert writer.is_alive() and not device.gate.is_set()
+    device.gate.set()
+    writer.join(10.0)
+    assert store.get("p") == b"x" * 64
+    assert store.redo_order_waits == 0
+    store.close()
+
+
+def test_a_crash_before_the_redo_lands_loses_no_returned_put(tmp_path):
+    store, _, journal, device = _gated_store(home=_torn_home(tmp_path))
+    store.put_many({"a": b"a1", "b": b"b1"})
+    store.put("a", b"a2")
+    device.hold()
+    device.fail = True
+    writer, out = _start(store.put_many, {"a": b"a3", "c": b"c1"})
+    assert device.entered.wait(10.0)
+    assert store._entries["a"].version == 3  # installed, redo not landed
+    store.crash()
+    journal.crash()
+    device.gate.set()  # the held write dies with the node
+    writer.join(10.0)
+    assert isinstance(out[0], InjectedIOError)  # never acknowledged
+    assert store.recover() == 2
+    assert store.get("a") == b"a2" and store.get("b") == b"b1"
+    with pytest.raises(KeyError):
+        store.get("c")
+    store.put("a", b"a4")  # nothing is left pending
+    assert store.get("a") == b"a4"
+    store.close(flush=False)
+
+
+def test_a_failed_redo_write_raises_and_leaves_the_put_installed_and_dirty():
+    store, home, _, device = _gated_store(flush_interval=3600.0)
+    device.hold()
+    device.fail = True
+    writer, out = _start(store.put_many, {"k": b"unacked"})
+    assert device.entered.wait(10.0)
+    device.gate.set()  # the held write fails
+    writer.join(10.0)
+    assert isinstance(out[0], InjectedIOError)  # never acknowledged
+    assert not device.contains("redo/wb/done/k")  # no marker landed
+    # the failed put is the value served and, once flushed, the home one
+    assert store.get("k") == b"unacked"
+    assert store.dirty_keys == ["k"]
+    device.fail = False
+    assert store.flush() == 1
+    assert home.get("k") == b"unacked" and store.dirty_keys == []
+    store.close()
+
+
+def test_same_key_redos_land_in_version_order(tmp_path):
+    store, _, journal, device = _gated_store(home=_torn_home(tmp_path))
+    device.hold()
+    first, _ = _start(store.put, "k", b"first")
+    assert device.entered.wait(10.0)
+    second, _ = _start(store.put, "k", b"second")
+    deadline = time.monotonic() + 10.0
+    while store.redo_order_waits == 0 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert store.redo_order_waits == 1  # the second put waits its turn
+    device.gate.set()
+    first.join(10.0)
+    second.join(10.0)
+    blobs = [v for k, v in device.writes if k == "redo/wbdata/k"]
+    assert blobs == [b"first", b"second"]
+    store.crash()
+    journal.crash()
+    assert store.recover() == 1
+    assert store.get("k") == b"second"
+    assert store._entries["k"].version == 2
+    store.close(flush=False)
+
+
+def test_flusher_and_demote_skip_a_key_whose_redo_is_in_flight():
+    store, home, _, device = _gated_store()
+    device.hold()
+    writer, _ = _start(store.put, "k", b"v")
+    assert device.entered.wait(10.0)
+    assert store.dirty_keys == ["k"]
+    assert store._flush_once() == 0  # a flusher round leaves it dirty
+    assert not store.demote("k")  # and no demotion lands it at home
+    assert store.level_of("k") == "dram" and not home.contains("k")
+    flusher, flushed = _start(store.flush)
+    device.gate.set()
+    writer.join(10.0)
+    flusher.join(10.0)
+    assert not flusher.is_alive() and not isinstance(flushed[0], Exception)
+    assert store.dirty_keys == [] and home.get("k") == b"v"
+    # the flushed key's redo record is retracted, blob and marker
+    assert not device.contains("redo/wbdata/k")
+    assert not device.contains("redo/wb/done/k")
+    store.close()
+
+
+def test_an_install_waits_for_the_redo_it_would_retract():
+    store, _, _, device = _gated_store()
+    device.hold()
+    writer, _ = _start(store.put, "k", b"paged")
+    assert device.entered.wait(10.0)
+    installer, _ = _start(store.install_many, {"k": b"installed"})
+    deadline = time.monotonic() + 10.0
+    while store.redo_order_waits == 0 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert store.redo_order_waits == 1
+    device.gate.set()
+    writer.join(10.0)
+    installer.join(10.0)
+    # the install retracted the landed record: replay cannot bring the
+    # older value back
+    assert not device.contains("redo/wbdata/k")
+    assert not device.contains("redo/wb/done/k")
+    assert store.get("k") == b"installed"
     store.close()
 
 

@@ -128,6 +128,33 @@ def test_lock_wait_is_timed_only_when_another_thread_holds_the_lock():
     assert put.attrs["bytes"] == 5
     assert wait.parent == put.sid and wait.t1 - wait.t0 >= 0.03
     assert wait.attrs == {"store": "lw"}
+    # a put of a key whose redo record another put is still writing
+    # waits for it, as a lock wait named for the redo
+    journaled = TieredStore(
+        [TierLevel("dram", DramTier(), None), TierLevel("home", DramTier())],
+        policy=PlacementPolicy(write_back=True), journal=StateCache(),
+        name="lr")
+    with journaled._mutex:
+        journaled._redo_pending.add("k")
+
+    def land():
+        time.sleep(0.05)
+        with journaled._redo_landed:
+            journaled._redo_pending.discard("k")
+            journaled._redo_landed.notify_all()
+
+    th = threading.Thread(target=land)
+    th.start()
+    t = time.perf_counter()
+    journaled.put("k", b"v")
+    th.join()
+    journaled.close()
+    put = [s for s in _mine(t, "tier.put")
+           if s.thread == threading.get_ident()][0]
+    wait = [s for s in _mine(t, "tier.lock_wait") if s.parent == put.sid]
+    assert len(wait) == 1 and wait[0].t1 - wait[0].t0 >= 0.03
+    assert wait[0].attrs == {"store": "lr", "lock": "redo"}
+    assert journaled.redo_order_waits == 1
 
 
 def test_journal_put_carries_the_fsyncs_it_issued(tmp_path):
