@@ -41,11 +41,11 @@ stateful FaaS usable; this module is that surface for Marvel:
     The raw engine report stays available as ``handle.raw``; unknown
     field reads fail loudly (``report.field("typo")`` raises).
 
-The façade *lowers* onto the existing engines — it re-implements no
-execution.  The legacy entry points (``run_job``, ``run_stages``,
-``run_loop``) survive as deprecation shims that delegate here via
-:meth:`MarvelClient.from_components`, byte-identical outputs and
-journaled resume included (asserted by ``tests/test_api.py``).
+The façade *lowers* onto the engines' own entry points
+(:func:`~repro.core.mapreduce.run_job`,
+:func:`~repro.core.dataflow.run_stages`,
+:func:`~repro.core.dataflow.run_loop`) — it re-implements no execution,
+and the engine layer never imports this module.
 
 See DESIGN.md §9 for the config schema, the lazy-plan lowering rules,
 and the lifecycle/ownership diagram.
@@ -53,7 +53,6 @@ and the lifecycle/ownership diagram.
 
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass, field, replace
 from typing import (
     Any,
@@ -529,11 +528,6 @@ class MarvelClient:
 
         with MarvelClient(ClusterConfig(tiers=("dram", "s3"))) as client:
             out = client.dataset(parts).map(f).shuffle().reduce(g).run()
-
-    :meth:`from_components` wraps pre-built components *without* owning
-    them — the legacy ``run_job``/``run_stages``/``run_loop`` shims
-    delegate through it, so old call sites run the exact same engine path
-    as façade users (byte-identical outputs, journaled resume intact).
     """
 
     def __init__(self, config: Optional[ClusterConfig] = None,
@@ -548,7 +542,6 @@ class MarvelClient:
         config.validate()
         self.config = config
         self._closed = False
-        self._owned = True
         self._dataset_seq = 0
         self.state: Optional[Tier] = None
         self.store: Optional[BlockStore] = None
@@ -720,38 +713,6 @@ class MarvelClient:
         self.runtime = self.gateway = self.scheduler = None
         self._closed = True
 
-    @classmethod
-    def from_components(
-        cls,
-        *,
-        store: Optional[BlockStore] = None,
-        state: Optional[Tier] = None,
-        scheduler: Optional[Scheduler] = None,
-        journal: Optional[StateCache] = None,
-        gateway: Optional[Gateway] = None,
-        name: str = "legacy",
-    ) -> "MarvelClient":
-        """Wrap pre-built components without taking ownership.
-
-        ``close`` on such a client is a no-op for the wrapped components
-        (the caller built them, the caller closes them).  This is the
-        deprecation-shim path: legacy entry points hand their arguments
-        here and run through the same façade methods as new code.
-        """
-        client = cls.__new__(cls)
-        client.config = ClusterConfig(name=name)
-        client._closed = False
-        client._owned = False
-        client.store = store
-        client.state = state
-        client.scheduler = scheduler
-        client.journal = journal
-        client.gateway = gateway
-        client.runtime = gateway.runtime if gateway is not None else None
-        client.cluster = None
-        client._dataset_seq = 0
-        return client
-
     # -- lifecycle ---------------------------------------------------------
     @property
     def closed(self) -> bool:
@@ -764,8 +725,6 @@ class MarvelClient:
         if self._closed:
             return
         self._closed = True
-        if not self._owned:
-            return
         if self.cluster is not None:
             # node 0's components are the client's own; the router closes
             # every node with the same gateway-then-runtime-then-tiers
@@ -1076,7 +1035,7 @@ class MarvelClient:
     ) -> JobHandle:
         """Run a :class:`~repro.core.mapreduce.MapReduceJob` on the
         client's stack (or explicit overrides).  This is the lowering
-        target of the dataset API and of the legacy ``run_job`` shim.
+        target of the dataset API.
         ``device`` (default: the config's mode) lowers the partition /
         eligible-reduce steps onto the Pallas kernel layer — output
         bytes are identical to host execution.
@@ -1107,7 +1066,7 @@ class MarvelClient:
                 net_seconds=net0.modeled_seconds - net_s0,
             )
             return handle
-        raw = _mapreduce._run_job_impl(
+        raw = _mapreduce.run_job(
             job,
             store if store is not None else self.store,
             input_path,
@@ -1137,7 +1096,7 @@ class MarvelClient:
         binds a device-execution context around tasks that opted in with
         ``StageTask(device=True)``."""
         self._check_open()
-        raw = _dataflow._run_stages_impl(
+        raw = _dataflow.run_stages(
             name,
             stages,
             state if state is not None else self.state,
@@ -1166,7 +1125,7 @@ class MarvelClient:
         between supersteps) with loop state pinned hot in the client's
         tier stack and per-iteration journaled commit markers."""
         self._check_open()
-        raw = _dataflow._run_loop_impl(
+        raw = _dataflow.run_loop(
             name,
             init,
             superstep,
@@ -1354,86 +1313,3 @@ class Dataset:
                     line for line in store.read(path).split(b"\n") if line
                 )
         return out
-
-
-# -- legacy entry-point delegation ---------------------------------------------
-
-def _deprecated(old: str, new: str) -> None:
-    # stacklevel: 1=this line, 2=_legacy_run_*, 3=the shim in core/*,
-    # 4=the user's call site — the frame the warning should name.
-    warnings.warn(
-        f"{old} is deprecated; use {new} (see DESIGN.md §9)",
-        DeprecationWarning,
-        stacklevel=4,
-    )
-
-
-def _legacy_run_job(
-    job: "_mapreduce.MapReduceJob",
-    store: BlockStore,
-    input_path: str,
-    output_path: str,
-    intermediate: Tier,
-    scheduler: Optional[Scheduler] = None,
-    journal: Optional[StateCache] = None,
-    fail_map_attempts: Optional[Dict[str, int]] = None,
-    mode: str = "wave",
-    gateway: Optional[Gateway] = None,
-    adaptive: bool = False,
-) -> "_mapreduce.JobReport":
-    _deprecated("repro.core.mapreduce.run_job",
-                "repro.api.MarvelClient.dataset(...).run() / .mapreduce(...)")
-    client = MarvelClient.from_components(
-        store=store, state=intermediate, scheduler=scheduler,
-        journal=journal, gateway=gateway,
-    )
-    return client.mapreduce(
-        job, input_path, output_path, mode=mode, adaptive=adaptive,
-        fail_map_attempts=fail_map_attempts,
-    ).raw
-
-
-def _legacy_run_stages(
-    name: str,
-    stages: Sequence[Stage],
-    state: Tier,
-    scheduler: Optional[Scheduler] = None,
-    journal: Optional[StateCache] = None,
-    gateway: Optional[Gateway] = None,
-    subscribers: Sequence[Callable] = (),
-    external_tokens: Sequence[str] = (),
-) -> "_dataflow.StageRunReport":
-    _deprecated("repro.core.dataflow.run_stages",
-                "repro.api.MarvelClient.stages(...)")
-    client = MarvelClient.from_components(
-        state=state, scheduler=scheduler, journal=journal, gateway=gateway,
-    )
-    return client.stages(
-        name, stages, subscribers=subscribers,
-        external_tokens=external_tokens,
-    ).raw
-
-
-def _legacy_run_loop(
-    name: str,
-    init: Callable[[LoopContext], None],
-    superstep: Callable[[LoopContext], Sequence[Stage]],
-    converged: Callable[[LoopContext], bool],
-    state: Tier,
-    scheduler: Optional[Scheduler] = None,
-    journal: Optional[StateCache] = None,
-    gateway: Optional[Gateway] = None,
-    max_iterations: int = 50,
-    pin_state: bool = True,
-    halt_after: Optional[int] = None,
-) -> "_dataflow.LoopReport":
-    _deprecated("repro.core.dataflow.run_loop",
-                "repro.api.MarvelClient.iterate(...)")
-    client = MarvelClient.from_components(
-        state=state, scheduler=scheduler, journal=journal, gateway=gateway,
-    )
-    return client.iterate(
-        name, init=init, superstep=superstep, until=converged,
-        max_iterations=max_iterations, pin_state=pin_state,
-        halt_after=halt_after,
-    ).raw

@@ -298,28 +298,6 @@ def run_stages(
     gateway: Optional["Gateway"] = None,
     subscribers: Sequence[Callable] = (),
     external_tokens: Sequence[str] = (),
-) -> StageRunReport:
-    """Deprecated entry point — delegate through the :mod:`repro.api`
-    façade (same engine, byte-identical outputs).  New code should use
-    :meth:`repro.api.MarvelClient.stages`."""
-    from repro.api import _legacy_run_stages
-
-    return _legacy_run_stages(
-        name, stages, state, scheduler=scheduler, journal=journal,
-        gateway=gateway, subscribers=subscribers,
-        external_tokens=external_tokens,
-    )
-
-
-def _run_stages_impl(
-    name: str,
-    stages: Sequence[Stage],
-    state: Tier,
-    scheduler: Optional[Scheduler] = None,
-    journal: Optional["StateCache"] = None,
-    gateway: Optional["Gateway"] = None,
-    subscribers: Sequence[Callable] = (),
-    external_tokens: Sequence[str] = (),
     device: Optional["DeviceExec"] = None,
 ) -> StageRunReport:
     """Execute a non-iterative N-stage dataflow job end to end.
@@ -520,31 +498,6 @@ def _sweep_stale_state(ctx: LoopContext, keep: int) -> None:
 
 
 def run_loop(
-    name: str,
-    init: Callable[[LoopContext], None],
-    superstep: Callable[[LoopContext], Sequence[Stage]],
-    converged: Callable[[LoopContext], bool],
-    state: Tier,
-    scheduler: Optional[Scheduler] = None,
-    journal: Optional["StateCache"] = None,
-    gateway: Optional["Gateway"] = None,
-    max_iterations: int = 50,
-    pin_state: bool = True,
-    halt_after: Optional[int] = None,
-) -> LoopReport:
-    """Deprecated entry point — delegate through the :mod:`repro.api`
-    façade (same engine, byte-identical outputs).  New code should use
-    :meth:`repro.api.MarvelClient.iterate`."""
-    from repro.api import _legacy_run_loop
-
-    return _legacy_run_loop(
-        name, init, superstep, converged, state, scheduler=scheduler,
-        journal=journal, gateway=gateway, max_iterations=max_iterations,
-        pin_state=pin_state, halt_after=halt_after,
-    )
-
-
-def _run_loop_impl(
     name: str,
     init: Callable[[LoopContext], None],
     superstep: Callable[[LoopContext], Sequence[Stage]],

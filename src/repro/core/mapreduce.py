@@ -575,33 +575,6 @@ def run_job(
     mode: str = "wave",
     gateway: Optional["Gateway"] = None,
     adaptive: bool = False,
-) -> JobReport:
-    """Deprecated entry point — delegate through the :mod:`repro.api`
-    façade (same engine, byte-identical outputs).  New code should build
-    a :class:`repro.api.MarvelClient` and use ``client.dataset(...)`` or
-    ``client.mapreduce(...)``."""
-    from repro.api import _legacy_run_job
-
-    return _legacy_run_job(
-        job, store, input_path, output_path, intermediate,
-        scheduler=scheduler, journal=journal,
-        fail_map_attempts=fail_map_attempts, mode=mode, gateway=gateway,
-        adaptive=adaptive,
-    )
-
-
-def _run_job_impl(
-    job: MapReduceJob,
-    store: BlockStore,
-    input_path: str,
-    output_path: str,
-    intermediate: Tier,
-    scheduler: Optional[Scheduler] = None,
-    journal: Optional[StateCache] = None,
-    fail_map_attempts: Optional[Dict[str, int]] = None,
-    mode: str = "wave",
-    gateway: Optional["Gateway"] = None,
-    adaptive: bool = False,
     device: Optional["DeviceExec"] = None,
 ) -> JobReport:
     """Execute ``job`` end to end (the engine behind the façade).

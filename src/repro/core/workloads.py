@@ -37,9 +37,9 @@ from repro.core.dataflow import (
     Stage,
     StageRunReport,
     StageTask,
-    _run_loop_impl,
-    _run_stages_impl,
     current_device_exec,
+    run_loop,
+    run_stages,
 )
 
 if TYPE_CHECKING:  # annotation only
@@ -215,7 +215,7 @@ def pagerank_loop(
         )
         return residual < tol
 
-    report = _run_loop_impl(
+    report = run_loop(
         name, init, superstep, converged, state,
         scheduler=scheduler, journal=journal, gateway=gateway,
         max_iterations=max_iterations, pin_state=pin_state,
@@ -424,7 +424,7 @@ def kmeans_loop(
         return ctx.result("update").value["shift"] < tol
 
     try:
-        report = _run_loop_impl(
+        report = run_loop(
             name, init, superstep, converged, state,
             scheduler=scheduler, journal=journal, gateway=gateway,
             max_iterations=max_iterations, pin_state=pin_state,
@@ -575,7 +575,7 @@ def terasort(
         run, outs = make_sort(j)
         sort_tasks.append(StageTask(f"sort_{j:03d}", run, outputs=outs))
 
-    return _run_stages_impl(
+    return run_stages(
         name,
         [
             Stage("sample", sample_tasks),

@@ -5,7 +5,10 @@ The seed ``serve_lm`` kept whole KV caches inside the function state blob
 entire cache and DRAM held every conversation ever admitted.  Here the
 function state shrinks to ``{session, t, tok}`` (a few hundred bytes,
 cheap to journal every commit) while the cache itself lives in the pager
-as per-(layer, block) tier keys.
+as per-(layer, block) tier keys.  Which leaves of the model's cache
+pytree are one pager layer is the pager's layout table's call
+(:func:`~repro.serving.kvpager.is_cache_layer`); this module names no
+cache type.
 
 Each step reads the session's layer list through :meth:`KVPager.load`
 (the resident handle when hot — no tier I/O), runs the stock
@@ -32,25 +35,18 @@ from repro.models import (
     init_cache,
     logits_fn,
 )
-from repro.models.attention import AttnCache
-from repro.models.mla import MLACache
-from repro.models.quant_cache import QuantAttnCache
-from repro.serving.kvpager import KVPager
+from repro.serving.kvpager import KVPager, is_cache_layer
 
 __all__ = ["PagedDecoder", "flatten_cache", "unflatten_cache"]
 
 
-def _is_layer(x: Any) -> bool:
-    return isinstance(x, (AttnCache, QuantAttnCache, MLACache))
-
-
 def flatten_cache(cache: Any) -> Tuple[List[Any], Any]:
-    """Cache pytree → flat list of per-layer caches + treedef.  Attention
-    and MLA latent caches stay whole (one pager layer each — the stacked
-    body caches ride as single leaves with a leading period axis);
-    anything else (ssm/rglru conv state) flattens to opaque array leaves
-    the pager stores whole."""
-    return jax.tree_util.tree_flatten(cache, is_leaf=_is_layer)
+    """Cache pytree → flat list of per-layer caches + treedef.  Every
+    cache type of the pager's layout table stays whole (one pager layer
+    each — the stacked body caches ride as single leaves with a leading
+    period axis); anything else (ssm/rglru conv state) flattens to opaque
+    array leaves the pager stores whole."""
+    return jax.tree_util.tree_flatten(cache, is_leaf=is_cache_layer)
 
 
 def unflatten_cache(treedef: Any, layers: List[Any]) -> Any:

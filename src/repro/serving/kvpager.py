@@ -22,11 +22,14 @@ block), so the hierarchy can place each session independently:
     comparison (the fig10 resume-TTFT contrast).
 
 The pager is deliberately ignorant of transformer structure: it pages a
-flat list of per-layer caches (:class:`AttnCache` /
-:class:`QuantAttnCache` / :class:`MLACache` latents / opaque array leaves
-for recurrent mixers);
-``decode_runtime`` owns the flatten/unflatten against the model's cache
-pytree.
+flat list of per-layer caches.  One table, ``_LAYOUTS``, owns their block
+layout: for each pageable type (:class:`AttnCache`,
+:class:`QuantAttnCache`, :class:`MLACache` latents) its kind name and
+each field's sequence axis.  Kind, length, slicing and joining all read
+it; any other leaf (a recurrent mixer's state) is opaque and stored
+whole.  ``decode_runtime`` owns the flatten/unflatten against the
+model's cache pytree, and tells layers from other leaves by the same
+table (:func:`is_cache_layer`).
 """
 
 from __future__ import annotations
@@ -44,7 +47,7 @@ from repro.models.mla import MLACache
 from repro.models.quant_cache import QuantAttnCache, quantize_kv
 from repro.storage import serde
 
-__all__ = ["KVPager", "PagerStats"]
+__all__ = ["KVPager", "PagerStats", "is_cache_layer"]
 
 #: per-layer kinds recorded in the session meta record
 _ATTN, _QUANT, _MLA, _OPAQUE = "attn", "quant", "mla", "opaque"
@@ -94,21 +97,33 @@ class _Session:
         return sum(self.sizes.values())
 
 
+#: The block layout of every pageable cache type, and the one place that
+#: decides it: the kind recorded in the session meta, then each field of
+#: the type with its sequence axis (negative, so a stacked body cache's
+#: leading period axis is carried along).  Every other leaf is opaque and
+#: stored whole as ``{"x": leaf}``.  Paging a new cache type is one row.
+_LAYOUTS: Dict[type, Tuple[str, Dict[str, int]]] = {
+    AttnCache: (_ATTN, {"k": -3, "v": -3}),
+    QuantAttnCache: (_QUANT, {"k_q": -3, "v_q": -3, "k_s": -2, "v_s": -2}),
+    MLACache: (_MLA, {"c_kv": -2, "k_pe": -2}),
+}
+_BY_KIND = {kind: (cls, axes) for cls, (kind, axes) in _LAYOUTS.items()}
+
+
+def is_cache_layer(x: Any) -> bool:
+    """Whether ``x`` is one pageable layer cache (a row of the table)."""
+    return type(x) in _LAYOUTS
+
+
 def _layer_kind(layer: Any) -> str:
-    if isinstance(layer, QuantAttnCache):
-        return _QUANT
-    if isinstance(layer, AttnCache):
-        return _ATTN
-    if isinstance(layer, MLACache):
-        return _MLA
-    return _OPAQUE
+    row = _LAYOUTS.get(type(layer))
+    return row[0] if row else _OPAQUE
 
 
 def _seq_len(layer: Any) -> int:
-    if isinstance(layer, MLACache):  # (..., S, r): sequence axis -2
-        return int(layer.c_kv.shape[-2])
-    arr = layer.k_q if isinstance(layer, QuantAttnCache) else layer.k
-    return int(arr.shape[-3])
+    # every field of a layer holds the same slots: the first one says
+    name, axis = next(iter(_LAYOUTS[type(layer)][1].items()))
+    return int(getattr(layer, name).shape[axis])
 
 
 def _quantize_layer(layer: AttnCache) -> QuantAttnCache:
@@ -118,49 +133,37 @@ def _quantize_layer(layer: AttnCache) -> QuantAttnCache:
 
 
 def _slice_block(layer: Any, lo: int, hi: int) -> Dict[str, Any]:
-    """One (layer, block) blob: the block's token slots from every array
-    of the layer cache.  Values/int8 carry the sequence axis at -3,
-    quant scales and MLA latents at -2; opaque leaves are stored whole."""
-    if isinstance(layer, QuantAttnCache):
-        return {
-            "k_q": layer.k_q[..., lo:hi, :, :],
-            "v_q": layer.v_q[..., lo:hi, :, :],
-            "k_s": layer.k_s[..., lo:hi, :],
-            "v_s": layer.v_s[..., lo:hi, :],
-        }
-    if isinstance(layer, AttnCache):
-        return {"k": layer.k[..., lo:hi, :, :], "v": layer.v[..., lo:hi, :, :]}
-    if isinstance(layer, MLACache):
-        return {"c_kv": layer.c_kv[..., lo:hi, :],
-                "k_pe": layer.k_pe[..., lo:hi, :]}
-    return {"x": layer}
+    """One (layer, block) blob: the block's token slots from every field
+    of the layer cache, along the field's sequence axis."""
+    row = _LAYOUTS.get(type(layer))
+    if row is None:
+        return {"x": layer}
+    return {
+        name: getattr(layer, name)[
+            (Ellipsis, slice(lo, hi)) + (slice(None),) * (-axis - 1)]
+        for name, axis in row[1].items()
+    }
 
 
 def _join_blocks(kind: str, parts: List[Dict[str, Any]]) -> Any:
     if kind == _OPAQUE:
         return jnp.asarray(parts[0]["x"])
-    cat = lambda name, axis: (
-        jnp.asarray(parts[0][name]) if len(parts) == 1
-        else jnp.concatenate([jnp.asarray(p[name]) for p in parts], axis=axis)
-    )
-    if kind == _QUANT:
-        return QuantAttnCache(
-            k_q=cat("k_q", -3), v_q=cat("v_q", -3),
-            k_s=cat("k_s", -2), v_s=cat("v_s", -2),
-        )
-    if kind == _MLA:
-        return MLACache(c_kv=cat("c_kv", -2), k_pe=cat("k_pe", -2))
-    return AttnCache(k=cat("k", -3), v=cat("v", -3))
+    cls, axes = _BY_KIND[kind]
+    fields = {}
+    for name, axis in axes.items():
+        arrs = [jnp.asarray(p[name]) for p in parts]
+        fields[name] = (arrs[0] if len(arrs) == 1
+                        else jnp.concatenate(arrs, axis=axis))
+    return cls(**fields)
 
 
 class KVPager:
     """Block-table KV paging for decode sessions over a tier stack.
 
-    ``store`` is duck-typed: a :class:`~repro.storage.hierarchy.
-    TieredStore` engages the full pin/demote/promote machinery; a plain
-    :class:`~repro.storage.kvcache.StateCache` (or single tier) degrades
-    gracefully — demotion then just rewrites blocks in their demoted
-    encoding wherever the store keeps them.
+    Every caller passes a :class:`~repro.storage.hierarchy.TieredStore`,
+    whose pin/demote/promote machinery places each session's blocks.
+    Those hooks are probed with ``getattr``, so a bare tier also serves
+    (without placement); see ROADMAP D6.
     """
 
     def __init__(
@@ -274,7 +277,7 @@ class KVPager:
             ent.t = int(t)
             ent.hot = True
             ent.quantized = any(
-                isinstance(l, QuantAttnCache) for l in ent.resident
+                _layer_kind(l) == _QUANT for l in ent.resident
             )
             self._touch(ent)
             pin = getattr(self.store, "pin", None)
@@ -414,7 +417,7 @@ class KVPager:
                     for l in ent.resident
                 ]
                 ent.quantized = any(
-                    isinstance(l, QuantAttnCache) for l in ent.resident
+                    _layer_kind(l) == _QUANT for l in ent.resident
                 )
                 self._write_blocks(ent, dirty=None)
             unpin = getattr(self.store, "unpin", None)

